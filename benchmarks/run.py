@@ -156,6 +156,9 @@ def main() -> None:
                          "(e.g. BENCH_kernel.json)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.compile_cache import use_persistent_cache
+
+    use_persistent_cache()
 
     print("name,us_per_call,derived")
     failed = 0

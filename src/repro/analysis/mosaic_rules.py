@@ -18,7 +18,7 @@ Tiling model (pallas guide):
   * the second-to-last ("sublane") dim must be a multiple of the dtype's
     sublane count (f32: 8, bf16: 16, int8/uint8: 32) — or equal the full dim,
     or be 1 (degenerate row blocks relayout fine);
-  * ``pl.Unblocked`` halo slabs must stay inside the zero-padded input rows;
+  * ``pl.Element`` halo slabs must stay inside the zero-padded input rows;
   * packed weights are exactly ``ceil(K/8)`` / ``ceil(C/8)`` bytes wide;
   * the conv kernel feeds the MXU fixed 128-row passes (``MXU_ROWS``).
 """
@@ -61,8 +61,8 @@ RULES: dict[str, Rule] = {r.id: r for r in [
     Rule("mosaic-sublane", ERROR,
          "block second-to-last dim must be a multiple of the dtype sublane "
          "(f32 8 / bf16 16 / u8 32), the full dim, or 1"),
-    Rule("unblocked-bounds", ERROR,
-         "pl.Unblocked halo slabs must stay inside the zero-padded input "
+    Rule("element-bounds", ERROR,
+         "pl.Element halo slabs must stay inside the zero-padded input "
          "rows"),
     Rule("mxu-pass-rows", ERROR,
          "the conv kernel's fixed MXU pass height must stay 128 rows"),

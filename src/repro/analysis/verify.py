@@ -216,7 +216,7 @@ def _verify_conv(instr: ConvInstr, idx: int, shape, budget: int):
     if geo["adv"] < 1 or geo["slab"] < kh \
             or last_slab_end > geo["padded_rows"]:
         fs.append(make_finding(
-            "unblocked-bounds", name, idx,
+            "element-bounds", name, idx,
             f"halo slabs (nt={geo['nt']}, adv={geo['adv']}, "
             f"slab={geo['slab']}) overrun the {geo['padded_rows']} padded "
             f"input rows"))
@@ -347,7 +347,7 @@ def _verify_dwconv(instr: DWConvInstr, idx: int, shape, budget: int):
     if geo["adv"] < 1 or geo["slab"] < kh \
             or last_slab_end > geo["padded_rows"]:
         fs.append(make_finding(
-            "unblocked-bounds", name, idx,
+            "element-bounds", name, idx,
             f"halo slabs (nt={geo['nt']}, adv={geo['adv']}, "
             f"slab={geo['slab']}) overrun the {geo['padded_rows']} padded "
             f"input rows"))

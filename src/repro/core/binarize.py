@@ -84,9 +84,12 @@ def solve_alpha(W: jax.Array, B: jax.Array, group_size: int) -> jax.Array:
     G = K // group_size
     Bf = B.astype(jnp.float32).reshape(M, G, group_size, N)
     Wf = W.astype(jnp.float32).reshape(G, group_size, N)
-    # Gram[G, N, M, M] and rhs[G, N, M]
-    gram = jnp.einsum("mgkn,lgkn->gnml", Bf, Bf)
-    rhs = jnp.einsum("mgkn,gkn->gnm", Bf, Wf)
+    # Gram[G, N, M, M] and rhs[G, N, M], at full fp32 precision: on a TPU the
+    # default would round W to bf16, and the packed weights a program is
+    # compiled with would then depend on the backend that binarized them.
+    hi = jax.lax.Precision.HIGHEST
+    gram = jnp.einsum("mgkn,lgkn->gnml", Bf, Bf, precision=hi)
+    rhs = jnp.einsum("mgkn,gkn->gnm", Bf, Wf, precision=hi)
     # Ridge for rank-deficient B (e.g. B_m == B_l): tiny relative jitter.
     eye = jnp.eye(M, dtype=jnp.float32)
     jitter = 1e-6 * jnp.maximum(jnp.trace(gram, axis1=-2, axis2=-1), 1.0)
@@ -323,12 +326,19 @@ def fake_quant(
     algorithm: int = 2,
     K_iters: int = 8,
     group_size: int | None = None,
+    m_active: int | None = None,
 ) -> jax.Array:
-    """QAT forward: W -> STE(binary reconstruction of W).  Differentiable."""
+    """QAT forward: W -> STE(binary reconstruction of W).  Differentiable.
+
+    ``m_active`` reconstructs from the first ``m_active`` of the M levels
+    only — the §IV-D runtime switch, as the packed deployment applies it."""
     approx = (algorithm2 if algorithm == 2 else algorithm1)(
         W, M, group_size=group_size,
         **({"K_iters": K_iters} if algorithm == 2 else {}),
     )
+    if m_active is not None:
+        approx = approx._replace(B=approx.B[:m_active],
+                                 alpha=approx.alpha[:m_active])
     # The binarization itself (incl. the Alg-2 while_loop) is not part of the
     # gradient path — STE routes dL/dW_hat straight to the latent weights.
     return ste_binarize(W, jax.lax.stop_gradient(reconstruct(approx)))

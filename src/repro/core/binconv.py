@@ -78,7 +78,8 @@ def conv2d(params: dict, x: jax.Array, *, stride: int = 1,
     elif quant.mode == "fake_quant":
         W = params["w"].reshape(K, -1).astype(jnp.float32)
         W_hat = bz.fake_quant(W, quant.M, algorithm=quant.algorithm,
-                              K_iters=quant.K_iters, group_size=quant.group_size)
+                              K_iters=quant.K_iters, group_size=quant.group_size,
+                              m_active=quant.m_active)
         y = flat @ W_hat.astype(flat.dtype)
     elif quant.mode == "binary":
         Kf = flat.shape[-1]
@@ -306,7 +307,8 @@ def depthwise_relu(params: dict, x: jax.Array, *, stride: int = 1,
         W_hat = bz.fake_quant(
             w.reshape(kh * kw, C).astype(jnp.float32), quant.M,
             algorithm=quant.algorithm, K_iters=quant.K_iters,
-            group_size=None)  # channel-wise, like binarize_dwconv_params
+            group_size=None,  # channel-wise, like binarize_dwconv_params
+            m_active=quant.m_active)
         w = W_hat.reshape(kh, kw, one, C).astype(x.dtype)
     y = _dwconv_fp(w, x, stride)
     if "b" in params:

@@ -101,7 +101,7 @@ def apply_linear(params: dict, x: jax.Array, qc: QuantConfig = DENSE) -> jax.Arr
         W = params["w"].astype(jnp.float32)
         W_hat = bz.fake_quant(
             W, qc.M, algorithm=qc.algorithm, K_iters=qc.K_iters,
-            group_size=qc.group_size,
+            group_size=qc.group_size, m_active=qc.m_active,
         )
         y = x @ W_hat.astype(x.dtype)
     else:

@@ -51,23 +51,17 @@ def cache_stats() -> dict:
     variants the executor holds.  ``trace_entries`` is the process-wide
     trace counter above; ``jit_cache_entries`` is the live entry count of
     ``_execute_jit``'s jit cache (one entry per distinct (program treedef,
-    input aval, schedule) triple) when the jax build exposes it."""
-    out = {"trace_entries": _trace_entries[0]}
-    try:
-        out["jit_cache_entries"] = _execute_jit._cache_size()
-    except Exception:  # noqa: BLE001 — private API, absent on some builds
-        pass
-    return out
+    input aval, schedule) triple)."""
+    return {"trace_entries": _trace_entries[0],
+            "jit_cache_entries": _execute_jit._cache_size()}
 
 
 def cache_gauges() -> dict:
     """``name -> callable`` gauges for ``repro.testing.soak`` — each must
     stay exactly flat once a soak workload has seen all its variants."""
-    gauges = {"exec_trace_entries": lambda: float(_trace_entries[0])}
-    if "jit_cache_entries" in cache_stats():
-        gauges["exec_jit_cache_entries"] = (
-            lambda: float(cache_stats()["jit_cache_entries"]))
-    return gauges
+    return {"exec_trace_entries": lambda: float(_trace_entries[0]),
+            "exec_jit_cache_entries": lambda: float(
+                _execute_jit._cache_size())}
 
 
 def _apply(instr, y: jax.Array, m: int, interpret: bool) -> jax.Array:

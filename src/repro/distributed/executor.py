@@ -37,7 +37,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.deploy import executor as dexec
@@ -120,14 +119,14 @@ def _layer_fn(mesh, axis_data: str, axis_model: str, shard: LayerShard,
                                       axis=y_loc.ndim - 1, tiled=True)
         return dexec._apply(instr, y, m, interpret)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(_instr_specs(shard, axis_model, itd), P(axis_data)),
         out_specs=P(axis_data),
         # replicated layers compute identically on every model column
         # (deterministic kernels, identical inputs/weights), so the output
         # is replicated along the model axis by construction
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn)
 
 

@@ -30,6 +30,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+from jax.sharding import AxisType
 
 from repro.deploy.program import BinArrayProgram, ConvInstr, TilePlan
 from repro.kernels import binary_conv as bck
@@ -86,9 +87,13 @@ class MeshPlan:
 
     def build_mesh(self) -> jax.sharding.Mesh:
         """Materialize the (n_data, n_model) device mesh.  Raises if the
-        process has fewer than ``devices`` JAX devices."""
+        process has fewer than ``devices`` JAX devices.  The axes are
+        ``Auto``: the executor places data itself through ``shard_map``, and
+        slicing a ragged batch off a sharded output needs no stated output
+        sharding then."""
         return jax.make_mesh((self.n_data, self.n_model),
-                             (self.axis_data, self.axis_model))
+                             (self.axis_data, self.axis_model),
+                             axis_types=(AxisType.Auto, AxisType.Auto))
 
     def describe(self) -> list[str]:
         """One human line per shard (tools/verify_program.py --mesh)."""

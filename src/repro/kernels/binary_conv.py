@@ -6,9 +6,9 @@ matmul ever runs, forfeiting the memory-stream win the paper's compression
 (Eq. 6) buys.  On the FPGA the AGU streams patches out of the feature buffer;
 here the kernel does the same job in VMEM:
 
-  1. AGU:  extract the patch tile for one input row-slab directly from the
-     input block with kh·kw static strided slices — the im2col tensor only
-     ever exists as a VMEM value, never in HBM.
+  1. AGU:  assemble the patch tile for one input row-slab directly from the
+     input block, kh·kw ref loads per conv row, into a VMEM scratch — the
+     im2col tensor never exists in HBM.
   2. PE/PA: unpack the bit-packed filters of all active levels to ±1, fold
      the per-(level, group) alpha in per K row, and run ONE level-concatenated
      MXU contraction ``[rows, m·K] @ [m·K, bd]`` (see below).
@@ -86,31 +86,37 @@ multiple of 128 (NB=5 → 245/256 = 96% occupancy).  Ragged batches
 so consecutive slabs overlap by the ``kh − stride`` halo rows the conv
 window needs across the tile boundary.  Overlapping blocks cannot be
 expressed in Pallas' default *Blocked* indexing (offsets are
-``index·block_shape``), so the x spec uses ``pl.Unblocked`` indexing: the
-index map returns element offsets directly, and the halo rows ride in via
-``t·adv`` with ``adv = BU·pool·stride < slab_rows``.  The wrapper zero-pads
-the row axis so every slab (including the ragged last tile when
-``Uo % BU != 0``) is fully in bounds; the zero rows only ever feed output
-rows that are sliced off after the call.
+``index·block_shape``), so the x spec gives every block dim as
+``pl.Element`` (element indexing is all dims or none): the index map
+returns element offsets directly, and the halo rows ride in via ``t·adv``
+with ``adv = BU·pool·stride < slab_rows``.  The wrapper zero-pads the row
+axis so every slab (including the ragged last tile when ``Uo % BU != 0``)
+is fully in bounds; the zero rows only ever feed output rows that are
+sliced off after the call.  It also folds every ``stride`` input columns
+into the lane axis (:func:`fold_columns`), so each tap of a strided conv
+is a contiguous ref load (Mosaic lowers no strided value slice).
 
 alpha/bias/weights are broadcast along the batch-tile and row-tile grid
 dims, x along the D grid dim; the row-tile dim is innermost so a weight
-tile stays resident while the x slabs stream through it.  Per-program
-working set (``tile_vmem_bytes`` computes the same quantities):
+tile stays resident while the x slabs stream through it.  Per-program VMEM
+(``tile_vmem_bytes`` computes the same quantities, each buffer padded to
+(8, 128) tiles, the pipelined blocks counted twice for double buffering):
 
-    x slab        NB·slab_rows·Wp·C·4            (fp32 input rows + halo)
-    patches       NB·u_tile·V·kh·kw·C·4·cat      (implicit im2col; cat = m+1
-                                                  counts the level-concat
-                                                  copy P_cat when m > 1)
-    weight tile   M·kh·kw·ceil(C/8)·BD           (bit-packed)
-    W_cat         2·m·kh·kw·C·BD·4               (±1 unpack + alpha-folded)
-    acc           NB·u_tile·V·BD·4
+    x slab        NB·slab_rows·Wf·stride·C·4     (fp32 input rows + halo)
+    weight tile   m·kh·kw·ceil(C/8)·BD           (bit-packed)
+    P_cat         rows_pad·m·K·4                 (scratch: implicit im2col,
+                                                  m level copies, rows
+                                                  padded to 128)
+    acc           rows_pad·BD·4                  (scratch: GEMM result)
+    W_cat         unpack bits + signs, alpha-folded ±1 weights
     out tile      NB·BU·Vo·BD·4                  (pooled HBM write)
 
 ``pick_tile`` co-picks (NB, BU) from a VMEM budget (default
-``DEFAULT_VMEM_BUDGET`` = 8 MiB, half a TPU core's VMEM, leaving room for
-double buffering): big early layers keep NB=1 and row-tile BU down until
-the slab fits; small late layers keep whole-image BU = Uo and pick the NB
+``DEFAULT_VMEM_BUDGET``: the compiler's 16 MiB default scoped-VMEM limit
+less headroom for Mosaic's temporaries; every call still sets its limit
+explicitly, ``binary_matmul.vmem_limit_bytes``): big early layers keep
+NB=1 and row-tile BU down until the slab fits; small late layers keep
+whole-image BU = Uo and pick the NB
 minimizing the whole batch's padded MXU rows (ragged-batch zero images
 charged) within the budget.  ``pick_bu`` is the BU-only special case (NB
 fixed).  Whole-image per-image blocking is
@@ -122,16 +128,22 @@ quantities.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import binarize as bz
+from repro.kernels import binary_matmul as bmk
+from repro.kernels.binary_matmul import dot_f32, unpack_signs
 
-# Per-program VMEM working-set budget for auto-picked tiles: half a TPU
-# core's ~16 MiB VMEM, leaving headroom for the pipeline's double buffering.
-DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024
+# VMEM budget for auto-picked tiles: the compiler's default scoped-VMEM limit
+# less the headroom kept for Mosaic's own temporaries.  ``tile_vmem_bytes``
+# counts the tiled, double-buffered footprint, so a plan within this budget
+# compiles without raising the limit.
+DEFAULT_VMEM_BUDGET = bmk.SCOPED_VMEM_LIMIT - bmk.VMEM_HEADROOM
 
 # MXU systolic-array row dimension: the GEMM row count a program feeds is
 # padded to a multiple of this, so occupancy = rows / roundup(rows, 128).
@@ -198,6 +210,25 @@ def repack_taps(B_packed: jax.Array, kh: int, kw: int, C: int) -> jax.Array:
 # Tile sizing (VMEM budget -> (NB, BU)) and MXU-occupancy analytics
 # ---------------------------------------------------------------------------
 
+def folded_width(Wp: int, C: int, stride: int) -> tuple[int, int]:
+    """``(Wf, Cf)``: the input's width and lane dims as the conv and dw
+    kernels see them.  The wrappers fold every ``stride`` consecutive
+    columns into the lane axis (a free row-major reshape after zero-padding
+    the width to a multiple of ``stride``), so tap ``(i, j)`` of output
+    column v is column ``v + j // stride`` at lanes ``(j % stride)·C`` and
+    up: every tap is a contiguous load, never a strided one."""
+    return -(-Wp // stride), stride * C
+
+
+def fold_columns(x: jax.Array, stride: int) -> jax.Array:
+    """[B, H, Wp, C] -> [B, H, Wf, stride·C] (see :func:`folded_width`)."""
+    B, H, Wp, C = x.shape
+    Wf, Cf = folded_width(Wp, C, stride)
+    if Wf * stride != Wp:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, Wf * stride - Wp), (0, 0)))
+    return x.reshape(B, H, Wf, Cf)
+
+
 def slab_rows(bu: int, kh: int, *, stride: int = 1, pool: int = 1) -> int:
     """Input rows one program needs for ``bu`` pooled output rows (halo incl.)."""
     return (bu * pool - 1) * stride + kh
@@ -245,27 +276,33 @@ def unpack_work_per_output(nb: int, bu: int, vo: int, K: int, *,
 def tile_vmem_bytes(W: int, C: int, kh: int, kw: int, bd: int, *, bu: int,
                     pool: int = 1, stride: int = 1, m: int = 1,
                     nb: int = 1) -> int:
-    """Analytic per-program VMEM working set of the fused conv kernel for an
-    ``nb``-image, ``bu``-pooled-row output tile (see the module docstring's
-    table).
+    """VMEM footprint of one fused-conv program for an ``nb``-image,
+    ``bu``-pooled-row output tile, as Mosaic lays it out (see the module
+    docstring's table): every buffer padded to (sublane, 128-lane) tiles,
+    the pipelined blocks counted twice (double buffering).
 
     ``W`` is the *padded* input width (SAME resolved upstream).  The same
-    numbers drive ``pick_tile``/``pick_bu`` and benchmarks/kernel_bench.py.
+    numbers drive ``pick_tile``/``pick_bu``, the kernel's scoped-VMEM limit
+    and benchmarks/kernel_bench.py.
     """
+    tb = bmk.vmem_tile_bytes
     V = (W - kw) // stride + 1
-    u_tile = bu * pool
     slab = slab_rows(bu, kh, stride=stride, pool=pool)
     c8 = -(-C // 8)
-    K = kh * kw * C
-    x_b = nb * slab * W * C * 4
-    # base patches + the level-concatenated P_cat copy (m > 1 only)
-    cat = 1 + (m if m > 1 else 0)
-    patches = nb * u_tile * V * K * 4 * cat
-    w_packed = m * kh * kw * c8 * bd
-    w_cat = 2 * m * K * bd * 4               # ±1 unpack + alpha-folded W_cat
-    acc = nb * u_tile * V * bd * 4
-    out = nb * bu * max(V // pool, 1) * bd * 4
-    return x_b + patches + w_packed + w_cat + acc + out
+    T = kh * kw
+    K = T * C
+    rows = nb * bu * pool * V
+    rows_pad = -(-rows // MXU_ROWS) * MXU_ROWS
+    blocks = (tb((nb * slab, *folded_width(W, C, stride)))
+              + tb((m * T, c8, bd), 1)
+              + tb((m, 1, bd)) + tb((1, bd))
+              + tb((nb * bu, max(V // pool, 1), bd)))
+    scratch = tb((rows_pad, m * K)) + tb((rows_pad, bd))   # P_cat, acc
+    # int32 bits + ±1 signs of the unpack, alpha-expanded and folded W_cat
+    weights = 2 * tb((m * T * c8, 8, bd)) + 2 * tb((m * K, bd))
+    # one P_cat row group being assembled, one pooled output row
+    values = 2 * tb((8 * V, m * K)) + (pool + 1) * tb((V, bd))
+    return 2 * blocks + scratch + weights + values
 
 
 def tile_hbm_bytes(W: int, C: int, kh: int, kw: int, bd: int, *, bu: int,
@@ -330,8 +367,9 @@ def conv_block_shapes(Hp: int, Wp: int, C: int, D: int, kh: int, kw: int, *,
     row_pad = max(rows_needed - Hp, 0)
     b = B if B is not None else nb
     Bp = b + (-b) % nb
+    Wf, Cf = folded_width(Wp, C, stride)
     blocks = {
-        "x": ((nb, slab, Wp, C), (Bp, Hp + row_pad, Wp, C), "float32"),
+        "x": ((nb, slab, Wf, Cf), (Bp, Hp + row_pad, Wf, Cf), "float32"),
         "B_tap_packed": ((m, T, C8, bd), (m, T, C8, Dp), "uint8"),
         "alpha": ((m, G, bd), (m, G, Dp), "float32"),
         "bias": ((1, bd), (1, Dp), "float32"),
@@ -413,37 +451,63 @@ def pick_tile(B: int, H: int, W: int, C: int, kh: int, kw: int, bd: int,
 # Kernel
 # ---------------------------------------------------------------------------
 
-def _kernel(x_ref, bp_ref, alpha_ref, bias_ref, o_ref, *,
+def _kernel(x_ref, bp_ref, alpha_ref, bias_ref, o_ref, p_ref, acc_ref, *,
             kh: int, kw: int, C: int, stride: int, pool: int, nb: int,
             u_tile: int, V: int, group_size: int, m_active: int, relu: bool):
-    """One (NB images, BD channels, BU rows) tile: patches + GEMM + epilogue."""
-    x = x_ref[...]                                   # [nb, slab_rows, Wp, C]
-    # --- AGU: implicit im2col, tap-major to match the K layout (i, j, c) ---
-    cols = []
-    for i in range(kh):
-        for j in range(kw):
-            xs = x[:, i: i + (u_tile - 1) * stride + 1: stride,
-                   j: j + (V - 1) * stride + 1: stride, :]
-            cols.append(xs.reshape(nb * u_tile * V, C))
-    patches = jnp.concatenate(cols, axis=1).astype(jnp.float32)  # [rows, K]
+    """One (NB images, BD channels, BU rows) tile: patches + GEMM + epilogue.
 
+    ``p_ref`` [rows_pad, m·K] and ``acc_ref`` [rows_pad, BD] are VMEM
+    scratch: the level-concatenated patch matrix P_cat and the GEMM result,
+    one row per (image, conv row, conv column) of the tile.
+    """
+    conv_rows = nb * u_tile
+    rows = conv_rows * V
     K = kh * kw * C
-    G = K // group_size
     bd = o_ref.shape[-1]
-    c8 = bp_ref.shape[2]
+
+    # --- AGU: implicit im2col, tap-major to match the K layout (i, j, c) ---
+    def patch_rows(r0, count):
+        """P_cat rows of conv rows r0 .. r0+count-1: per conv row, each tap
+        is one contiguous load of the column-folded input slab."""
+        blocks = []
+        for k in range(count):
+            n, u = (r0 + k) // u_tile, (r0 + k) % u_tile
+            taps = [x_ref[n, u * stride + i, pl.ds(j // stride, V),
+                          (j % stride) * C:(j % stride + 1) * C]
+                    for i in range(kh) for j in range(kw)]
+            blocks.append(jnp.concatenate(taps, axis=1))
+        p = jnp.concatenate(blocks, axis=0).astype(jnp.float32)
+        return jnp.concatenate([p] * m_active, axis=1)
+
+    # P_cat stores start on an 8-row boundary, so conv rows go in groups of
+    # g with g·V % 8 == 0; a ragged last group is stored at a static offset.
+    g = 8 // math.gcd(V, 8)
+    n_groups = conv_rows // g
+
+    def fill(q, carry):
+        p_ref[pl.ds(pl.multiple_of(q * g * V, 8), g * V), :] = patch_rows(
+            q * g, g)
+        return carry
+
+    if n_groups:
+        jax.lax.fori_loop(0, n_groups, fill, 0)
+    if conv_rows % g:
+        p_ref[n_groups * g * V:rows, :] = patch_rows(
+            n_groups * g, conv_rows % g)
+    if p_ref.shape[0] > rows:
+        p_ref[rows:, :] = jnp.zeros((p_ref.shape[0] - rows, m_active * K),
+                                    jnp.float32)
+
     # --- PA: unpack every active level at once, fold alpha per level-row ---
-    shifts = jax.lax.broadcasted_iota(
-        jnp.uint8, (m_active, kh * kw, c8, 8, 1), 3)
-    bits = (bp_ref[...][:, :, :, None, :] >> shifts) & jnp.uint8(1)
-    w = (bits.astype(jnp.int8) * 2 - 1).reshape(m_active, kh * kw, c8 * 8, bd)
-    w = w[:, :, :C, :].reshape(m_active, K, bd).astype(jnp.float32)
+    G = K // group_size
+    w = unpack_signs(bp_ref[...])                    # [m, kh·kw, c8·8, bd]
+    w = w[:, :, :C, :].reshape(m_active, K, bd)
     a = alpha_ref[...]                               # [m, G, bd]
     a_exp = jnp.broadcast_to(
         a[:, :, None, :], (m_active, G, group_size, bd)).reshape(
         m_active, K, bd)
     w_cat = (w * a_exp).reshape(m_active * K, bd)    # level-major row blocks
-    p_cat = (jnp.concatenate([patches] * m_active, axis=1)
-             if m_active > 1 else patches)           # [rows, m·K]
+
     # One contraction per program, issued in fixed MXU-row-sized passes:
     # every pass is an identical-shape [MXU_ROWS, m·K] @ [m·K, bd] dot (zero
     # row padding on the ragged last pass), so each output row's reduction
@@ -451,27 +515,33 @@ def _kernel(x_ref, bp_ref, alpha_ref, bias_ref, o_ref, *,
     # guarantee — and matches how the MXU consumes the row dimension.  A
     # single [rows, m·K] dot would let the backend re-block the reduction
     # as a function of the row count, which differs across tilings.
-    rows = nb * u_tile * V
-    r_pad = (-rows) % MXU_ROWS
-    if r_pad:
-        p_cat = jnp.concatenate(
-            [p_cat, jnp.zeros((r_pad, m_active * K), jnp.float32)], axis=0)
-    acc = jax.lax.map(
-        lambda pc: jax.lax.dot_general(
-            pc, w_cat,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32),
-        p_cat.reshape((rows + r_pad) // MXU_ROWS, MXU_ROWS, m_active * K),
-    ).reshape(rows + r_pad, bd)[:rows]
+    def one_pass(r, carry):
+        r0 = pl.multiple_of(r * MXU_ROWS, MXU_ROWS)
+        acc_ref[pl.ds(r0, MXU_ROWS), :] = dot_f32(
+            p_ref[pl.ds(r0, MXU_ROWS), :], w_cat)
+        return carry
+
+    jax.lax.fori_loop(0, p_ref.shape[0] // MXU_ROWS, one_pass, 0)
+
     # --- AMU epilogue: bias + 2D max-pool + ReLU, then the only HBM write ---
-    y = acc + bias_ref[0][None, :]
-    y = y.reshape(nb, u_tile, V, bd)
-    if pool > 1:
-        y = y.reshape(nb, u_tile // pool, pool, V // pool, pool, bd).max(
-            axis=(2, 4))
-    if relu:
-        y = jnp.maximum(y, 0.0)
-    o_ref[...] = y
+    bu = u_tile // pool
+    bias = bias_ref[...]                             # [1, bd]
+
+    def epilogue(q, carry):
+        n, uo = q // bu, q % bu
+        y = None
+        for pi in range(pool):                       # pool conv rows ...
+            r = n * u_tile + uo * pool + pi
+            a = acc_ref[pl.ds(r * V, V), :] + bias   # [V, bd]
+            y = a if y is None else jnp.maximum(y, a)
+        if pool > 1:                                 # ... and pool columns
+            y = y.reshape(V // pool, pool, bd).max(axis=1)
+        if relu:
+            y = jnp.maximum(y, 0.0)
+        o_ref[n, uo] = y
+        return carry
+
+    jax.lax.fori_loop(0, nb * bu, epilogue, 0)
 
 
 @functools.partial(
@@ -512,7 +582,8 @@ def binary_conv2d_pallas(
     §III-B — binconv.relu_maxpool asserts the same).  ``nb`` fixes the batch
     tile (images folded into the GEMM row dim per program) and ``bu`` the
     row tile (pooled output rows per program); leaving both None co-picks
-    them from ``vmem_budget`` (default 8 MiB) via :func:`pick_tile` —
+    them from ``vmem_budget`` (default ``DEFAULT_VMEM_BUDGET``) via
+    :func:`pick_tile` —
     whole-image NB=1 blocking whenever that already saturates the MXU.
     Giving ``bu`` alone keeps per-image blocking (nb=1).  Every (nb, bu)
     tiling is bit-identical: each output element's concatenated m·K
@@ -558,6 +629,7 @@ def binary_conv2d_pallas(
     row_pad = max(rows_needed - Hp, 0)      # ragged rows: zero rows — both
     if b_rem or row_pad:                    # sliced off after the call
         x = jnp.pad(x, ((0, b_rem), (0, row_pad), (0, 0), (0, 0)))
+    x = fold_columns(x, stride)
     Bp = B + b_rem
     u_tile = bu * pool
 
@@ -568,6 +640,9 @@ def binary_conv2d_pallas(
     # row-tile dim innermost: the weight tile stays resident per D-tile
     # while the x slabs stream through it.
     grid = (Bp // nb, Dp // bd, nt)
+    rows_pad = -(-(nb * u_tile * V) // MXU_ROWS) * MXU_ROWS
+    footprint = tile_vmem_bytes(Wp, C, kh, kw, bd, bu=bu, pool=pool,
+                                stride=stride, m=m_active, nb=nb)
     out = pl.pallas_call(
         functools.partial(
             _kernel, kh=kh, kw=kw, C=C, stride=stride, pool=pool, nb=nb,
@@ -575,10 +650,10 @@ def binary_conv2d_pallas(
             relu=relu),
         grid=grid,
         in_specs=[
-            # overlapping halo slabs need element offsets -> Unblocked
-            pl.BlockSpec((nb, slab, Wp, C),
-                         lambda b, d, t: (b * nb, t * adv, 0, 0),
-                         indexing_mode=pl.Unblocked()),
+            # overlapping halo slabs need element offsets -> pl.Element
+            # (all dims or none, so the batch offset is in elements too)
+            pl.BlockSpec(tuple(map(pl.Element, (nb, slab, *x.shape[2:]))),
+                         lambda b, d, t: (b * nb, t * adv, 0, 0)),
             pl.BlockSpec((m_active, T, C8, bd), lambda b, d, t: (0, 0, 0, d)),
             pl.BlockSpec((m_active, G, bd), lambda b, d, t: (0, 0, d)),
             pl.BlockSpec((1, bd), lambda b, d, t: (0, d)),
@@ -587,6 +662,11 @@ def binary_conv2d_pallas(
                                lambda b, d, t: (b, t, 0, d)),
         out_shape=jax.ShapeDtypeStruct((Bp, nt * bu, V // pool, Dp),
                                        jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((rows_pad, m_active * T * C), jnp.float32),
+            pltpu.VMEM((rows_pad, bd), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=bmk.vmem_limit_bytes(footprint)),
         interpret=interpret,
     )(x, B_tap_packed, alpha, bias2)
     return out[:B, :uo, :, :D]

@@ -14,8 +14,8 @@ This kernel keeps the whole dw stage on-chip:
      ``sum_m alpha[m,c]·B[m,t,c]`` collapses the level loop into the
      reconstruction W_hat the paper's Eq. 1 defines (HBM traffic stays the
      packed bits + alpha; m_active < M truncates the sum, §IV-D);
-  2. accumulate the kh·kw strided-slice taps channel-wise on the VPU
-     (no matmul — there is nothing to contract);
+  2. accumulate the kh·kw taps channel-wise on the VPU, one output row at
+     a time (no matmul — there is nothing to contract);
   3. bias + ReLU epilogue before the only HBM write-back.
 
 ``B_tap_packed`` weight layout (channel-wise, byte-aligned per tap)
@@ -43,12 +43,14 @@ fill here — the tap accumulation runs on the VPU — so the batch tile is
 purely an unpack/dispatch amortization; ragged batches ride on zero-padded
 images sliced off after the call).  Row tiles use the same halo-slab scheme
 as the conv kernel: tile ``t`` reads the input rows
-``[t·BU·stride, t·BU·stride + (BU-1)·stride + kh)`` via a ``pl.Unblocked``
+``[t·BU·stride, t·BU·stride + (BU-1)·stride + kh)`` via a ``pl.Element``
 element-offset index map, with the wrapper zero-padding the row axis so
-ragged last tiles stay in bounds.  ``pick_tile_dw`` co-picks (NB, BU) from
-the same 8 MiB default budget: row-tiled maps keep NB=1, whole-image maps
-grow NB until the budget binds (``pick_bu_dw`` is the BU-only
-special case).
+ragged last tiles stay in bounds and folding ``stride`` columns into the
+lane axis so every tap is a contiguous load.  ``pick_tile_dw`` co-picks
+(NB, BU) from the conv kernel's default budget (``DEFAULT_VMEM_BUDGET``,
+counted as tiled, double-buffered VMEM): row-tiled maps keep NB=1,
+whole-image maps grow NB until the budget binds (``pick_bu_dw`` is the
+BU-only special case).
 """
 from __future__ import annotations
 
@@ -57,10 +59,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import binarize as bz
+from repro.kernels import binary_matmul as bmk
 from repro.kernels.binary_conv import (DEFAULT_VMEM_BUDGET, _note_plan_pick,
-                                       slab_rows)
+                                       fold_columns, folded_width, slab_rows)
 
 
 def pack_dw_taps(B: jax.Array) -> jax.Array:
@@ -86,17 +90,21 @@ def unpack_dw_taps(packed: jax.Array, C: int) -> jax.Array:
 
 def tile_vmem_bytes_dw(W: int, C: int, kh: int, kw: int, *, bu: int,
                        stride: int = 1, m: int = 1, nb: int = 1) -> int:
-    """Analytic per-program VMEM working set for an ``nb``-image, ``bu``-row
-    dw tile."""
+    """VMEM footprint of one ``nb``-image, ``bu``-row dw program as Mosaic
+    lays it out: (sublane, 128-lane) tiled buffers, the pipelined blocks
+    counted twice (double buffering)."""
+    tb = bmk.vmem_tile_bytes
     V = (W - kw) // stride + 1
     slab = slab_rows(bu, kh, stride=stride)
     c8 = -(-C // 8)
-    x_b = nb * slab * W * C * 4
-    w_packed = m * kh * kw * c8
-    w_eff = kh * kw * c8 * 8 * 4 * (m + 1)   # unpacked levels + folded taps
-    acc = nb * bu * V * C * 4
-    out = nb * bu * V * C * 4
-    return x_b + w_packed + w_eff + acc + out
+    T = kh * kw
+    blocks = (tb((nb * slab, *folded_width(W, C, stride)))
+              + tb((m, T, c8), 1) + tb((m, C))
+              + tb((1, C)) + tb((nb * bu, V, C)))
+    # int32 bits (8 lanes per byte before the merge), ±1 levels, folded taps
+    weights = 2 * tb((m * T * c8, 8)) + tb((m * T, C)) + tb((T, C))
+    values = 3 * tb((V, C))                 # one output row: tap, acc, out
+    return 2 * blocks + weights + values
 
 
 def dw_block_shapes(Hp: int, Wp: int, C: int, kh: int, kw: int, *,
@@ -116,8 +124,9 @@ def dw_block_shapes(Hp: int, Wp: int, C: int, kh: int, kw: int, *,
     row_pad = max(rows_needed - Hp, 0)
     b = B if B is not None else nb
     Bp = b + (-b) % nb
+    Wf, Cf = folded_width(Wp, C, stride)
     blocks = {
-        "x": ((nb, slab, Wp, C), (Bp, Hp + row_pad, Wp, C), "float32"),
+        "x": ((nb, slab, Wf, Cf), (Bp, Hp + row_pad, Wf, Cf), "float32"),
         "B_tap_packed": ((m, T, c8), (m, T, c8), "uint8"),
         "alpha": ((m, C), (m, C), "float32"),
         "bias": ((1, C), (1, C), "float32"),
@@ -169,26 +178,40 @@ def _dw_kernel(x_ref, bp_ref, alpha_ref, bias_ref, o_ref, *,
                kh: int, kw: int, C: int, stride: int, nb: int,
                u_tile: int, V: int, m_active: int, relu: bool):
     """One (NB images, BU rows) tile: fold levels, tap-accumulate, epilogue."""
-    x = x_ref[...].astype(jnp.float32)               # [nb, slab, Wp, C]
     T, c8 = bp_ref.shape[1], bp_ref.shape[2]
     # fold the level sum into one effective fp tap weight per (tap, channel):
     # W_hat[t, c] = sum_{m < m_active} alpha[m, c] * B[m, t, c]  (Eq. 1)
-    shifts = jax.lax.broadcasted_iota(jnp.uint8, (1, T, c8, 8), 3)
-    bits = (bp_ref[...][:, :, :, None] >> shifts) & jnp.uint8(1)
-    w = (bits.astype(jnp.int8) * 2 - 1).reshape(m_active, T, c8 * 8)
-    w = w[:, :, :C].astype(jnp.float32)              # [m, T, C] ±1
+    # Channels sit on lanes, so the unpack spreads each byte's 8 bits over 8
+    # consecutive lanes; the bit arithmetic runs in 32-bit integers.
+    shape = (m_active, T, c8, 8)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, shape, 3)
+    packed = jnp.broadcast_to(
+        bp_ref[...].astype(jnp.int32)[:, :, :, None], shape)
+    bits = (packed >> shifts) & 1
+    w = (bits * 2 - 1).astype(jnp.float32).reshape(m_active, T, c8 * 8)
+    w = w[:, :, :C]                                  # [m, T, C] ±1
     eff = jnp.sum(w * alpha_ref[...][:, None, :], axis=0)     # [T, C]
-    # channel-wise tap accumulation on the VPU (no contraction to feed MXU)
-    acc = jnp.zeros((nb, u_tile, V, C), jnp.float32)
-    for i in range(kh):
-        for j in range(kw):
-            xs = x[:, i: i + (u_tile - 1) * stride + 1: stride,
-                   j: j + (V - 1) * stride + 1: stride, :]
-            acc = acc + xs * eff[i * kw + j][None, None, None, :]
-    y = acc + bias_ref[0][None, None, :]
-    if relu:
-        y = jnp.maximum(y, 0.0)
-    o_ref[...] = y
+    # channel-wise tap accumulation on the VPU (no contraction to feed MXU),
+    # one output row per step; each tap is one contiguous load of the
+    # column-folded slab
+    bias = bias_ref[...]                             # [1, C]
+
+    def row(r, carry):
+        n, u = r // u_tile, r % u_tile
+        acc = jnp.zeros((V, C), jnp.float32)
+        for i in range(kh):
+            for j in range(kw):
+                xs = x_ref[n, u * stride + i, pl.ds(j // stride, V),
+                           (j % stride) * C:(j % stride + 1) * C]
+                t = i * kw + j
+                acc = acc + xs.astype(jnp.float32) * eff[t:t + 1, :]
+        y = acc + bias
+        if relu:
+            y = jnp.maximum(y, 0.0)
+        o_ref[n, u] = y
+        return carry
+
+    jax.lax.fori_loop(0, nb * u_tile, row, 0)
 
 
 @functools.partial(
@@ -252,6 +275,7 @@ def binary_dwconv2d_pallas(
     row_pad = max(rows_needed - Hp, 0)      # tile: zero pad, sliced off below
     if b_rem or row_pad:
         x = jnp.pad(x, ((0, b_rem), (0, row_pad), (0, 0), (0, 0)))
+    x = fold_columns(x, stride)
     Bp = B + b_rem
 
     bp = B_tap_packed[:m_active]
@@ -259,21 +283,24 @@ def binary_dwconv2d_pallas(
     bias2 = bias.astype(jnp.float32).reshape(1, C)
 
     grid = (Bp // nb, nt)
+    footprint = tile_vmem_bytes_dw(Wp, C, kh, kw, bu=bu, stride=stride,
+                                   m=m_active, nb=nb)
     out = pl.pallas_call(
         functools.partial(
             _dw_kernel, kh=kh, kw=kw, C=C, stride=stride, nb=nb,
             u_tile=bu, V=V, m_active=m_active, relu=relu),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((nb, slab, Wp, C),
-                         lambda b, t: (b * nb, t * adv, 0, 0),
-                         indexing_mode=pl.Unblocked()),
+            pl.BlockSpec(tuple(map(pl.Element, (nb, slab, *x.shape[2:]))),
+                         lambda b, t: (b * nb, t * adv, 0, 0)),
             pl.BlockSpec((m_active, T, c8), lambda b, t: (0, 0, 0)),
             pl.BlockSpec((m_active, C), lambda b, t: (0, 0)),
             pl.BlockSpec((1, C), lambda b, t: (0, 0)),
         ],
         out_specs=pl.BlockSpec((nb, bu, V, C), lambda b, t: (b, t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, nt * bu, V, C), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=bmk.vmem_limit_bytes(footprint)),
         interpret=interpret,
     )(x, bp, alpha, bias2)
     return out[:B, :U]

@@ -28,7 +28,8 @@ VMEM blocking (BlockSpec, all multiples of MXU-friendly sizes)
 --------------------------------------------------------------
     x        [T, K]            -> blocks [BT, BK]
     B_packed [M, K/8, N] uint8 -> blocks [m_active, BK/8, BN]
-    alpha    [M, G, N]         -> blocks [m_active, 1, BN]   (G = K/group_size)
+    alpha    [M, G, N]         -> blocks [m_active, BN]  (group axis squeezed,
+                                  G = K/group_size)
     out      [T, N] f32        -> blocks [BT, BN]
 
 Grid: (T/BT, N/BN, K/BK) with the K dimension innermost ("arbitrary"
@@ -36,11 +37,15 @@ sequential), accumulating into the output block; alpha's group index is
 derived from the K block index (requires group_size % BK == 0 or BK == K —
 otherwise ops.py falls back to the single-K-block mode where the whole
 padded K is one block and alpha is folded into the unpacked weights per
-row).  Per-tile VMEM working set (fp32 x, defaults BT=BN=128, BK=256,
-M=2): ``BT*BK*4 + M*(BK/8)*BN + BT*BN*4`` ≈ 128 KiB + 8 KiB + 64 KiB —
-comfortably inside one core's ~16 MiB, leaving headroom for double
-buffering; ``benchmarks/kernel_bench.py tile_stats`` prints the same
-formula per candidate block shape.
+row).  ``tile_vmem_bytes_mm`` counts the VMEM footprint as Mosaic lays it
+out: every block padded to (sublane, 128-lane) tiles and double-buffered by
+the pipeline, plus the unpacked weight tile and the accumulator — about
+0.9 MiB at BT=BN=128, BK=256, M=2, far inside the 16 MiB scoped limit
+(``SCOPED_VMEM_LIMIT``) every call passes explicitly.
+
+Bit unpacking runs in 32-bit integers (``unpack_signs``) and every dot runs
+at full fp32 contraction precision (``dot_f32``); the conv and depth-wise
+kernels share both helpers.
 
 The per-level unpack costs BK/8 * BN uint8 VMEM loads per (BK x BN) tile —
 1/16 the bytes of a bf16 weight tile, which is exactly the paper's
@@ -53,13 +58,47 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+
+# The scoped-VMEM limit the TPU compiler enforces on a kernel unless the call
+# raises it (16 MiB on v5e).  Every pallas_call here passes its limit
+# explicitly through ``vmem_limit_bytes``: this floor, raised to the tile
+# plan's footprint plus ``VMEM_HEADROOM`` (room for Mosaic's own temporaries)
+# when a plan needs more.
+SCOPED_VMEM_LIMIT = 16 * 1024 * 1024
+VMEM_HEADROOM = 4 * 1024 * 1024
+
+
+def vmem_limit_bytes(footprint: int) -> int:
+    """The scoped-VMEM limit a kernel call asks the compiler for."""
+    return max(SCOPED_VMEM_LIMIT, footprint + VMEM_HEADROOM)
+
+
+def vmem_tile_bytes(shape, itemsize: int = 4) -> int:
+    """Bytes one VMEM buffer of ``shape`` occupies under Mosaic's register
+    tiling: the last dim pads to 128 lanes and the second-to-last to the
+    dtype's sublane count (32 / itemsize: f32 8, uint8 32)."""
+    shape = tuple(int(d) for d in shape)
+    if len(shape) < 2:
+        shape = (1,) * (2 - len(shape)) + shape
+    *lead, sub, lane = shape
+    n = 1
+    for d in lead:
+        n *= d
+    sub_tile = 32 // itemsize
+    return (n * -(-sub // sub_tile) * sub_tile * -(-lane // 128) * 128
+            * itemsize)
 
 
 def tile_vmem_bytes_mm(bt: int, bn: int, bk: int, *, m: int = 1) -> int:
-    """Analytic per-tile VMEM working set of the matmul kernel: fp32 x block
-    + bit-packed weight block + fp32 accumulator (module docstring formula).
+    """Tiled VMEM footprint of one matmul program: the double-buffered x,
+    packed-weight, alpha and output blocks, plus one level's unpacked ±1
+    weight tile (int32 bits and fp32 signs) and the fp32 accumulator.
     Shared by the deploy compiler's LayerStats and repro.analysis."""
-    return bt * bk * 4 + m * (bk // 8) * bn + bt * bn * 4
+    blocks = (vmem_tile_bytes((bt, bk)) + m * vmem_tile_bytes((bk // 8, bn), 1)
+              + vmem_tile_bytes((m, bn)) + vmem_tile_bytes((bt, bn)))
+    return 2 * blocks + 2 * vmem_tile_bytes((bk, bn)) + vmem_tile_bytes((bt, bn))
 
 
 def matmul_block_shapes(T: int, K: int, N: int, *, bt: int, bn: int, bk: int,
@@ -79,14 +118,41 @@ def matmul_block_shapes(T: int, K: int, N: int, *, bt: int, bn: int, bk: int,
     Kp = K_pad + (-K_pad) % bk
     Tp = T + (-T) % bt
     Np = N + (-N) % bn
-    alpha_block = (m, G, bn) if full_groups else (m, 1, bn)
+    # the kernel's alpha view: the whole [m, G, BN] in single-K-block grouped
+    # mode, else this K block's group row with the group axis squeezed
+    alpha = (((m, G, bn), (m, G, Np)) if full_groups
+             else ((m, bn), (m, Np)))
     blocks = {
         "x": ((bt, bk), (Tp, Kp), "float32"),
         "B_packed": ((m, bk // 8, bn), (m, Kp // 8, Np), "uint8"),
-        "alpha": (alpha_block, (m, G, Np), "float32"),
+        "alpha": (*alpha, "float32"),
         "out": ((bt, bn), (Tp, Np), "float32"),
     }
     return blocks, bk
+
+
+def unpack_signs(packed: jax.Array) -> jax.Array:
+    """Kernel-side unpack: uint8 ``[..., k8, n]`` (LSB-first along the
+    reduction axis) -> fp32 ±1 ``[..., 8*k8, n]``.
+
+    The bit arithmetic runs in 32-bit integers: Mosaic builds no 8-bit iota,
+    and the ``[k8, 8, n] -> [8*k8, n]`` merge is a tile-aligned shape cast
+    only for 32-bit element types (8 sublanes per tile)."""
+    *lead, k8, n = packed.shape
+    p = packed.astype(jnp.int32)[..., :, None, :]
+    shape = (*lead, k8, 8, n)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 2)
+    bits = (jnp.broadcast_to(p, shape) >> shifts) & 1
+    return (bits * 2 - 1).astype(jnp.float32).reshape(*lead, k8 * 8, n)
+
+
+def dot_f32(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` at full fp32 contraction precision — on the MXU the default
+    for fp32 operands may round them to bf16."""
+    return jax.lax.dot_general(
+        a, b, dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def _kernel(x_ref, bp_ref, alpha_ref, o_ref, *, m_active: int, n_k_blocks: int,
@@ -97,7 +163,8 @@ def _kernel(x_ref, bp_ref, alpha_ref, o_ref, *, m_active: int, n_k_blocks: int,
     the whole (padded) K lives in one block and alpha arrives as [M, G, BN],
     applied per K row by folding it into the unpacked ±1 weights.  This is
     the legal path for group sizes that are not multiples of 8 (no packed
-    K-tile boundary can align with the group boundaries then).
+    K-tile boundary can align with the group boundaries then).  Otherwise
+    alpha arrives as this K block's group row, ``[M, BN]``.
     """
     k_idx = pl.program_id(2)
 
@@ -107,12 +174,8 @@ def _kernel(x_ref, bp_ref, alpha_ref, o_ref, *, m_active: int, n_k_blocks: int,
 
     xb = x_ref[...].astype(jnp.float32)           # [BT, BK]
     acc = jnp.zeros(o_ref.shape, jnp.float32)     # [BT, BN]
-    bk8 = bp_ref.shape[1]
-    shifts = jax.lax.broadcasted_iota(jnp.uint8, (bk8, 8, 1), 1)
     for m in range(m_active):                     # static unroll over levels
-        packed = bp_ref[m]                        # [BK/8, BN] uint8
-        bits = (packed[:, None, :] >> shifts) & jnp.uint8(1)
-        bpm = (bits.astype(jnp.int8) * 2 - 1).reshape(-1, packed.shape[-1])
+        bpm = unpack_signs(bp_ref[m])             # [BK, BN] ±1
         if full_groups_size:
             a = alpha_ref[m]                      # [G, BN]
             G, bn = a.shape
@@ -122,18 +185,9 @@ def _kernel(x_ref, bp_ref, alpha_ref, o_ref, *, m_active: int, n_k_blocks: int,
             kp = bpm.shape[0]
             if kp > G * full_groups_size:         # 8-padding rows (x is zero)
                 a_exp = jnp.pad(a_exp, ((0, kp - G * full_groups_size), (0, 0)))
-            acc = acc + jax.lax.dot_general(
-                xb, bpm.astype(jnp.float32) * a_exp,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            acc = acc + dot_f32(xb, bpm * a_exp)
         else:
-            p = jax.lax.dot_general(
-                xb, bpm.astype(jnp.float32),
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )                                     # [BT, BN]
-            acc = acc + alpha_ref[m, 0, :][None, :] * p
+            acc = acc + alpha_ref[m:m + 1, :] * dot_f32(xb, bpm)
     o_ref[...] = o_ref[...] + acc
 
 
@@ -200,14 +254,15 @@ def binary_matmul_pallas(
     n_k_blocks = Kp // bk
     grid = (Tp // bt, Np // bn, n_k_blocks)
 
-    # group index of K-block k: (k * bk) // group_size  (static ints)
-    def alpha_idx(t, n, k):
-        return (0, (k * bk) // group_size if G > 1 else 0, n)
-
     if full_groups:
         alpha_spec = pl.BlockSpec((m_active, G, bn), lambda t, n, k: (0, 0, n))
     else:
-        alpha_spec = pl.BlockSpec((m_active, 1, bn), alpha_idx)
+        # the group axis is squeezed: K block k reads group (k * bk) //
+        # group_size, and the kernel sees a lane-dense [m, BN] block
+        alpha_spec = pl.BlockSpec(
+            (m_active, None, bn),
+            lambda t, n, k: (0, (k * bk) // group_size if G > 1 else 0, n))
+    footprint = tile_vmem_bytes_mm(bt, bn, bk, m=m_active)
     out = pl.pallas_call(
         functools.partial(_kernel, m_active=m_active, n_k_blocks=n_k_blocks,
                           full_groups_size=group_size if full_groups else 0),
@@ -219,6 +274,8 @@ def binary_matmul_pallas(
         ],
         out_specs=pl.BlockSpec((bt, bn), lambda t, n, k: (t, n)),
         out_shape=jax.ShapeDtypeStruct((Tp, Np), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes(footprint)),
         interpret=interpret,
     )(x, B_packed, alpha)
     return out[:T, :N]

@@ -47,19 +47,15 @@ def pick_matmul_plan(T: int, K: int, N: int, *, G: int,
     bn = _pick_block(N, 128)
     if bn < 128:
         bn = -(-N // 8) * 8
-    # bk must divide group_size (or G == 1); cap at 256 for VMEM
-    if G == 1:
-        bk = _pick_block(K8 * 8, 256)
-        if bk < 128:
-            bk = K8 * 8          # whole padded K in one (legal) block
-    elif group_size % 8 == 0:
-        bk = _pick_block(group_size, 256)
-        while group_size % bk and bk > 8:
-            bk //= 2  # terminates at a legal divisor: 8 | group_size
+    # bk must divide group_size (or G == 1); cap at 256 for VMEM.  Sublane
+    # legality (`mosaic-sublane`): the packed block's BK/8 uint8 rows must be
+    # a multiple of 32, so a K tile is 256 rows or the whole padded K.
+    if G == 1 or group_size % 256 == 0:
+        bk = 256 if K8 * 8 > 256 else K8 * 8
     else:
-        # group_size % 8 != 0: no multiple-of-8 K tile can align with group
-        # boundaries, so take the kernel's single-block grouped-alpha path
-        # (whole padded K in one block, alpha folded in per row).
+        # no legal K tile aligns with the group boundaries, so take the
+        # kernel's single-block grouped-alpha path (whole padded K in one
+        # block, alpha folded in per row).
         bk = K8 * 8
     return bt, bn, bk
 

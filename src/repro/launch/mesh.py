@@ -8,16 +8,24 @@ domain; sharding rules place only DP/FSDP traffic on it.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the LM stack places activations
+    with ``with_sharding_constraint`` and leaves the rest to the compiler,
+    which ``jax.make_mesh``'s default ``Explicit`` axes do not allow."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
     """Tiny mesh over the real local devices (tests / CPU examples)."""
     n = len(jax.devices())
     assert n % model_axis == 0
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return _auto_mesh((n // model_axis, model_axis), ("data", "model"))

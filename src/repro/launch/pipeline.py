@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -73,11 +72,11 @@ def pipeline_apply(stage_fn, params_stacked, x, *, mesh: Mesh,
         outs = jax.lax.psum(outs, "pipe")
         return outs.reshape(B, *x_local.shape[1:])
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(P("pipe"), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(params_stacked, x)
 

@@ -146,7 +146,14 @@ def init_embedding(key, vocab: int, dim: int, dtype):
 
 
 def embed(params, tokens: jax.Array) -> jax.Array:
-    return params["table"][tokens]
+    """Token embedding lookup.  Under explicitly sharded mesh axes the
+    gather's output sharding is ambiguous (the table is sharded over both
+    axes), so it is stated: the embeddings follow the tokens' sharding, with
+    the feature dim unsharded, on the table's mesh."""
+    table = params["table"]
+    spec = P(*jax.typeof(tokens).sharding.spec, None)
+    return table.at[tokens].get(
+        out_sharding=jax.typeof(table).sharding.update(spec=spec))
 
 
 def unembed(params, x: jax.Array) -> jax.Array:

@@ -139,6 +139,17 @@ class TestSTE:
         expect = bz.reconstruct(bz.algorithm2(W, M=3, K_iters=20))
         np.testing.assert_allclose(np.asarray(got), np.asarray(expect), rtol=1e-6)
 
+    @pytest.mark.parametrize("m_active", [1, 2, 3])
+    def test_fake_quant_m_active_keeps_leading_levels(self, m_active):
+        """§IV-D: m_active of M levels reconstructs from the first m_active
+        levels of the M-level approximation, as the packed path applies."""
+        W = _rand_w(jax.random.PRNGKey(14), 24, 8)
+        a = bz.algorithm2(W, M=3, K_iters=20)
+        expect = bz.reconstruct(a._replace(B=a.B[:m_active],
+                                           alpha=a.alpha[:m_active]))
+        got = bz.fake_quant(W, M=3, K_iters=20, m_active=m_active)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(expect))
+
 
 @settings(max_examples=25, deadline=None)
 @given(

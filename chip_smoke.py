@@ -203,9 +203,12 @@ def serve(program, rung: int, x) -> None:
     check(not bad, f"rung {rung}: service counters {bad}")
     check(st["completed"] == len(reqs),
           f"rung {rung}: {st['completed']} of {len(reqs)} completed")
+    split = ", ".join(f"{k} {v * 1e3:.2f}"
+                      for k, v in st["stage_s"].items())
     print(f"CNNService rung {rung}: {st['completed']} requests done in "
           f"{st['batches']} batches, {st['selftest_runs']} self-tests, "
-          f"bit-equal to deploy.execute", flush=True)
+          f"bit-equal to deploy.execute; smoke timing: ms per stage, summed "
+          f"over the batches: {split}", flush=True)
 
 
 def phase_serve(program, ladder, seed: int) -> None:

@@ -17,10 +17,16 @@ batch and the §IV-D ``m_active`` level schedule:
 
 The schedule is static (level counts select packed buffer slices), so each
 distinct schedule compiles once and is cached by ``jax.jit``.
+
+Each instruction runs under a ``jax.named_scope``, ``binarray.iNN.<name>``,
+which only adds metadata to its ops: :func:`op_owners` reads it back from
+the compiled forward to map every op a profiler trace names to its
+instruction.
 """
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -94,8 +100,9 @@ def _execute_jit(program: BinArrayProgram, x: jax.Array,
                  m_schedule: tuple[int, ...], interpret: bool) -> jax.Array:
     _trace_entries[0] += 1          # runs at trace time only, not per call
     y = x
-    for instr, m in zip(program.instrs, m_schedule):
-        y = _apply(instr, y, m, interpret)
+    for idx, (instr, m) in enumerate(zip(program.instrs, m_schedule)):
+        with jax.named_scope(f"binarray.{_owner(idx, instr.name)}"):
+            y = _apply(instr, y, m, interpret)
     return y
 
 
@@ -140,3 +147,82 @@ def execute(program: BinArrayProgram, x: jax.Array, m_active=None, *,
     sched = program.resolve_schedule(m_active)
     itp = program.interpret if interpret is None else interpret
     return _execute_jit(program, x, m_schedule=sched, interpret=itp)
+
+
+# ---------------------------------------------------------------------------
+# Which instruction owns each op of the compiled forward
+# ---------------------------------------------------------------------------
+
+def _owner(idx: int, name: str) -> str:
+    return f"i{idx:02d}.{name}"
+
+
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*)$")
+_OPCODE = re.compile(r"(?:^|\s)([a-z][\w-]*)\(")
+_OPERAND = re.compile(r"%([\w.-]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_SCOPE = re.compile(r"binarray\.(i\d+\.[^/]+)")
+_INSTR_ARG = re.compile(r"program\.instrs\[(\d+)\]")
+_NOT_OPS = frozenset({"parameter", "constant", "bitcast", "tuple"})
+
+
+def _hlo_owners(text: str, names) -> dict[str, str]:
+    """Owner of each op of the entry computation of compiled HLO ``text``
+    (the ops a TPU runs one by one; a fusion's body is one op), for a
+    program whose instructions are named ``names``.  See :func:`op_owners`
+    for the rules."""
+    owners: dict[str, str] = {}
+    out: dict[str, str] = {}
+    lines = text[text.index("\nENTRY "):].splitlines()[1:]
+    for line in lines:
+        if line.startswith("}"):
+            break
+        m = _HLO_OP.match(line)
+        if m is None:
+            continue
+        op, rest = m.groups()
+        code = _OPCODE.search(rest)
+        meta = _OP_NAME.search(rest)
+        op_name = meta.group(1) if meta else ""
+        scope = _SCOPE.search(op_name)
+        arg = _INSTR_ARG.match(op_name)
+        if scope:
+            owner = scope.group(1)
+        elif arg:
+            idx = int(arg.group(1))
+            owner = _owner(idx, names[idx])
+        elif op_name == "x":
+            owner = "input"
+        else:
+            operands = _OPERAND.findall(
+                rest[code.end():rest.index(")", code.end())])
+            owner = next((owners[o] for o in operands
+                          if owners.get(o, "unattributed") != "unattributed"),
+                         "unattributed")
+        owners[op] = owner
+        if code.group(1) not in _NOT_OPS:
+            out[op] = owner
+    return out
+
+
+def op_owners(program: BinArrayProgram, x, m_active=None) -> dict[str, str]:
+    """Map each op of the compiled forward, named as a profiler trace names
+    it (``"binary_conv2d_pallas.14"``, ``"pad.88"``), to the instruction
+    that owns it, ``"iNN.<name>"``, for reducing a device trace to time per
+    instruction.  ``x`` may be an array or a ``jax.ShapeDtypeStruct``, and
+    so may the program's weights: the forward is lowered and compiled for
+    the devices they name (or the default one), never run.
+
+    An op's owner is, in this order: (a) the instruction whose
+    ``binarray.iNN.<name>`` scope its ``op_name`` metadata holds; (b) the
+    instruction whose weights it comes from (``op_name`` ``program.instrs[N]
+    ...``: a layout copy of a weight); (c) ``"input"`` for the input's own
+    relayout (``op_name="x"``); (d) the owner of its first operand that has
+    one (weight prefetches, ``copy-start``/``copy-done``, carry no
+    metadata); (e) ``"unattributed"``.  Parameters, constants, bitcasts and
+    tuples are left out: they take no device time of their own.
+    """
+    sched = program.resolve_schedule(m_active)
+    compiled = _execute_jit.lower(program, x, m_schedule=sched,
+                                  interpret=program.interpret).compile()
+    return _hlo_owners(compiled.as_text(), [i.name for i in program.instrs])

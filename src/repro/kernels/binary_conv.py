@@ -668,5 +668,6 @@ def binary_conv2d_pallas(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=bmk.vmem_limit_bytes(footprint)),
         interpret=interpret,
+        name="binary_conv2d_pallas",
     )(x, B_tap_packed, alpha, bias2)
     return out[:B, :uo, :, :D]

@@ -302,5 +302,6 @@ def binary_dwconv2d_pallas(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=bmk.vmem_limit_bytes(footprint)),
         interpret=interpret,
+        name="binary_dwconv2d_pallas",
     )(x, bp, alpha, bias2)
     return out[:B, :U]

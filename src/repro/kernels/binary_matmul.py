@@ -277,5 +277,6 @@ def binary_matmul_pallas(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes(footprint)),
         interpret=interpret,
+        name="binary_matmul_pallas",
     )(x, B_packed, alpha)
     return out[:T, :N]

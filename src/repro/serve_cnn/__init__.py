@@ -8,11 +8,11 @@ binary levels before it sheds requests, and recovers to full-M when the
 pressure clears.  See docs/serving_cnn.md.
 """
 from repro.serve_cnn.service import (CNNService, ImageRequest,
-                                     NonFiniteOutput, SHED_REASONS)
+                                     NonFiniteOutput, SHED_REASONS, STAGES)
 from repro.serve_cnn.slo import (SLOConfig, SLOController, default_ladder,
                                  schedule_cost)
 
 __all__ = [
     "CNNService", "ImageRequest", "NonFiniteOutput", "SHED_REASONS",
-    "SLOConfig", "SLOController", "default_ladder", "schedule_cost",
+    "STAGES", "SLOConfig", "SLOController", "default_ladder", "schedule_cost",
 ]

@@ -51,17 +51,23 @@ reference for bit-exactness checks.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import itertools
 import time
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.deploy.program import BinArrayProgram
 from repro.serve_cnn.slo import SLOConfig, SLOController, default_ladder
 
 SHED_REASONS = ("queue_full", "deadline_expired", "slo_shed")
+# the stages of one step(), in order; each is a ``binarray.serve.<stage>``
+# span inside the step's ``binarray.serve.step`` span, and a sum of seconds
+# in ``stats["stage_s"]``
+STAGES = ("watchdog", "assemble", "execute", "screen", "fetch", "respond")
 
 
 class NonFiniteOutput(RuntimeError):
@@ -81,6 +87,9 @@ class ImageRequest:
     failed ones carry ``error``.  Completed requests carry the served
     ``logits``, the resolved ``m_schedule``/``rung`` they were computed at,
     their ``batch_index`` into the padded batch, and ``latency_s``.
+    Requests taken into a batch carry ``dispatch_t`` (service clock),
+    ``queue_wait_s`` (``dispatch_t - submit_t``) and ``step``, the id of the
+    ``step()`` that served them (the ``step`` of its trace span).
     """
 
     image: np.ndarray
@@ -95,6 +104,9 @@ class ImageRequest:
     batch_index: int | None = None
     submit_t: float = 0.0
     latency_s: float | None = None
+    dispatch_t: float | None = None
+    queue_wait_s: float | None = None
+    step: int | None = None
 
 
 class CNNService:
@@ -196,6 +208,7 @@ class CNNService:
             slo, initial_rung=initial_rung)
         self.queue: collections.deque[ImageRequest] = collections.deque()
         self._ids = itertools.count()
+        self._step_ids = itertools.count()
         self._latencies = collections.deque(maxlen=512)
         self._schedules_seen: set[tuple[int, ...]] = set()
         self.last_batch: np.ndarray | None = None
@@ -208,6 +221,8 @@ class CNNService:
             "fault_types": {}, "rung_hist": {},
             "selftest_runs": 0, "selftest_failures": 0, "reloads": 0,
             "quarantined_steps": 0,
+            "batch_slots": 0, "images_batched": 0,
+            "stage_s": {name: 0.0 for name in STAGES},
         }
         self._last_rung = self.controller.rung
 
@@ -260,39 +275,84 @@ class CNNService:
         one SLO update.  Returns every request that left the system this
         step (done, failed, or shed-at-dispatch).  The integrity watchdog
         (when configured) runs *before* batch assembly, so a corrupt program
-        is replaced before it can answer this step's requests."""
-        if self.selftest_every is not None:
-            self._watchdog()
+        is replaced before it can answer this step's requests.
+
+        Each call is one ``binarray.serve.step`` profiler span (``step``,
+        ``rung``, ``filled``) around one child span per stage of
+        :data:`STAGES`; each stage's seconds on ``clock`` add up in
+        ``stats["stage_s"]``."""
+        step_id = next(self._step_ids)
+        with TraceAnnotation("binarray.serve.step", step=step_id,
+                             rung=self.controller.rung) as span:
+            if self.selftest_every is not None:
+                with self._stage("watchdog"):
+                    self._watchdog()
+            with self._stage("assemble"):
+                finished, batch, x_np, x = self._assemble(step_id)
+            span.set_metadata(filled=len(batch))
+            if not batch:
+                return finished
+            rung = self.controller.rung
+            sched = self.controller.schedule
+            out, err = self._run_batch(x, rung, sched)
+            with self._stage("respond"):
+                self._respond(batch, out, err, rung, sched, x_np, finished)
+            return finished
+
+    @contextlib.contextmanager
+    def _stage(self, name: str):
+        """One stage of :meth:`step`: a profiler span, and its seconds on
+        ``clock`` added to ``stats["stage_s"][name]``."""
+        t0 = self.clock()
+        try:
+            with TraceAnnotation(f"binarray.serve.{name}"):
+                yield
+        finally:
+            self._stats["stage_s"][name] += self.clock() - t0
+
+    def _assemble(self, step_id: int):
+        """Dequeue up to ``batch_size`` live requests (shedding those whose
+        deadline passed in the queue) and zero-pad them into the device
+        batch.  Returns (shed requests, batch, host batch, device batch)."""
         finished: list[ImageRequest] = []
         batch: list[ImageRequest] = []
         while self.queue and len(batch) < self.batch_size:
             req = self.queue.popleft()
-            if (req.deadline_s is not None
-                    and req.deadline_s <= self.clock()):
+            now = self.clock()
+            if req.deadline_s is not None and req.deadline_s <= now:
                 finished.append(self._shed(req, "deadline_expired"))
                 continue
+            req.dispatch_t = now
+            req.queue_wait_s = now - req.submit_t
+            req.step = step_id
             batch.append(req)
         if not batch:
-            return finished
-
-        rung = self.controller.rung
-        sched = self.controller.schedule
+            return finished, batch, None, None
         shape = (self.batch_size,) + tuple(self.program.input_shape[1:])
         x_np = np.zeros(shape, np.float32)
         for i, req in enumerate(batch):
             x_np[i] = req.image
-        x = jnp.asarray(x_np)
+        self._stats["batch_slots"] += self.batch_size
+        self._stats["images_batched"] += len(batch)
+        return finished, batch, x_np, jnp.asarray(x_np)
 
+    def _run_batch(self, x, rung: int, sched):
+        """Execute with bounded retry and screen the logits.  Returns (host
+        logits or None, the last error)."""
         out, err = None, None
         for attempt in range(self.max_retries + 1):
             try:
-                y = self._execute(x, sched)
-                if not bool(jnp.all(jnp.isfinite(y))):
+                with self._stage("execute"):
+                    y = self._execute(x, sched)
+                with self._stage("screen"):
+                    finite = bool(jnp.all(jnp.isfinite(y)))
+                if not finite:
                     self._stats["nonfinite_detected"] += 1
                     raise NonFiniteOutput(
                         f"non-finite logits at rung {rung} "
                         f"(schedule {sched})")
-                out = np.asarray(y)
+                with self._stage("fetch"):
+                    out = np.asarray(y)
                 break
             except Exception as e:  # noqa: BLE001 — disposition by contract
                 err = e
@@ -303,8 +363,14 @@ class CNNService:
                     self._stats["exec_exceptions"] += 1
                 if attempt < self.max_retries:
                     self._stats["retries"] += 1
-                    self.sleep(self.backoff_s * (2 ** attempt))
+                    with self._stage("execute"):    # the retry's backoff
+                        self.sleep(self.backoff_s * (2 ** attempt))
+        return out, err
 
+    def _respond(self, batch, out, err, rung: int, sched, x_np,
+                 finished: list[ImageRequest]) -> None:
+        """Record the batch's outcome on each request and in ``stats``, and
+        run one SLO update."""
         self._stats["batches"] += 1
         self._stats["rung_hist"][rung] = (
             self._stats["rung_hist"].get(rung, 0) + 1)
@@ -334,7 +400,6 @@ class CNNService:
                 self._stats["completed"] += 1
                 finished.append(req)
         self.controller.update()
-        return finished
 
     # --------------------------------------------------------- watchdog ---
     def _watchdog(self) -> None:

@@ -310,3 +310,63 @@ class TestMobileNetB2:
         for a, b in zip(jax.tree_util.tree_leaves(prog),
                         jax.tree_util.tree_leaves(back)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# A compiled forward cut down to one op of each ownership rule of
+# executor.op_owners; the fused computation's body is not a device op.
+HLO = """HloModule jit__execute_jit
+
+%fused_computation (param_0: f32[4]) -> f32[4] {
+  %param_0 = f32[4]{0} parameter(0)
+  ROOT %negate.1 = f32[4]{0} negate(%param_0), metadata={op_name="jit(_execute_jit)/binarray.i01.fc/neg"}
+}
+
+ENTRY %main.9 (w.1: u8[4], x.1: f32[4]) -> f32[4] {
+  %w.1 = u8[4]{0} parameter(0), metadata={op_name="program.instrs[1].B_packed"}
+  %x.1 = f32[4]{0} parameter(1), metadata={op_name="x"}
+  %constant.1 = f32[] constant(0), metadata={op_name="jit(_execute_jit)/binarray.i00.c0/pad"}
+  %constant.2 = pred[4]{0} constant({1, 0, 1, 0})
+  %copy.1 = f32[4]{0} copy(%x.1), metadata={op_name="x"}
+  %pad.3 = f32[4]{0} pad(%copy.1, %constant.1), padding=0_0, metadata={op_name="jit(_execute_jit)/binarray.i00.c0/jit(binary_conv2d_pallas)/pad"}
+  %binary_conv2d_pallas.2 = f32[4]{0} custom-call(%pad.3), custom_call_target="tpu_custom_call", metadata={op_name="jit(_execute_jit)/binarray.i00.c0/jit(binary_conv2d_pallas)/binary_conv2d_pallas"}
+  %copy-start = (u8[4]{0}, u8[4]{0}, u32[]) copy-start(%w.1)
+  %copy-done = u8[4]{0} copy-done(%copy-start)
+  %copy.2 = u8[4]{0} copy(%copy-done), metadata={op_name="program.instrs[1].B_packed"}
+  %copy-start.1 = (pred[4]{0}, pred[4]{0}, u32[]) copy-start(%constant.2)
+  %copy-done.1 = pred[4]{0} copy-done(%copy-start.1)
+  %bitcast.1 = f32[4]{0} bitcast(%binary_conv2d_pallas.2)
+  %tuple.1 = (f32[4]{0}, u8[4]{0}) tuple(%bitcast.1, %copy.2)
+  ROOT %fusion = f32[4]{0} fusion(%bitcast.1, %copy.2, %copy-done.1), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(_execute_jit)/binarray.i01.fc/neg"}
+}
+"""
+
+
+class TestOpOwners:
+    def test_ownership_rules_on_compiled_text(self):
+        from repro.deploy.executor import _hlo_owners
+
+        assert _hlo_owners(HLO, ["c0", "fc"]) == {
+            "copy.1": "input",                        # (c) op_name "x"
+            "pad.3": "i00.c0",                        # (a) its scope
+            "binary_conv2d_pallas.2": "i00.c0",
+            "copy-start": "i01.fc",                   # (d) its operand's
+            "copy-done": "i01.fc",
+            "copy.2": "i01.fc",                       # (b) the weights'
+            "copy-start.1": "unattributed",           # (e)
+            "copy-done.1": "unattributed",
+            "fusion": "i01.fc",
+        }
+
+    def test_each_scope_maps_to_its_instruction(self):
+        """On the CPU, in interpret mode: every instruction of a small
+        program owns ops of the compiled forward under its own index."""
+        from repro.deploy.executor import op_owners
+        from repro.testing.scenarios import tiny_cnn_program
+
+        prog = tiny_cnn_program(batch=2)
+        x = jax.ShapeDtypeStruct(prog.input_shape, jnp.float32)
+        owners = op_owners(prog, x)
+        want = {f"i{i:02d}.{instr.name}"
+                for i, instr in enumerate(prog.instrs)}
+        assert want <= set(owners.values()) <= want | {"input",
+                                                        "unattributed"}

@@ -20,8 +20,8 @@ import numpy as np
 import pytest
 
 from repro import deploy
-from repro.serve_cnn import (CNNService, SLOConfig, default_ladder,
-                             schedule_cost)
+from repro.serve_cnn import (STAGES, CNNService, SLOConfig,
+                             default_ladder, schedule_cost)
 from repro.testing.faults import ManualClock
 from repro.testing.scenarios import tiny_cnn_program
 
@@ -229,6 +229,117 @@ class TestSLOController:
         assert svc.controller.rung == 1            # one decision, one rung
         # only the post-change step's 4 samples remain
         assert len(svc.controller._window) == 4
+
+
+# ---------------------------------------------------------------------------
+# where a step's time goes: stage seconds, queue waits, batch fill, step ids
+# ---------------------------------------------------------------------------
+
+class TestStageAccounting:
+    def test_stage_seconds_sum_to_the_steps(self, program, monkeypatch):
+        """Each stage's seconds on the service clock land in its own key;
+        the watchdog's self-test and a retry's backoff included."""
+        from repro.deploy import selftest
+
+        clock = ManualClock()
+        calls = [0]
+
+        def execute_fn(prog, x, sched, *, interpret=None):
+            calls[0] += 1
+            clock.advance(0.01)
+            if calls[0] == 1:
+                raise RuntimeError("transient")
+            return deploy.execute(prog, x, sched, interpret=interpret)
+
+        def slow_self_test(prog, rungs=None):
+            clock.advance(0.2)
+            return len(rungs)
+
+        monkeypatch.setattr(selftest, "self_test", slow_self_test)
+        svc = CNNService(program, batch_size=4, clock=clock,
+                         sleep=clock.sleep, backoff_s=0.5,
+                         execute_fn=execute_fn, selftest_every=1)
+        for im in _images(6):
+            svc.submit(im)
+        t0 = clock()
+        done = svc.drain()
+        assert [r.status for r in done] == ["done"] * 6
+        st = svc.stats["stage_s"]
+        assert tuple(st) == STAGES
+        assert st["watchdog"] == pytest.approx(2 * 0.2)    # two steps
+        # three dispatches (one failed) and one backoff
+        assert st["execute"] == pytest.approx(3 * 0.01 + 0.5)
+        assert st["assemble"] == st["screen"] == st["fetch"] == 0.0
+        assert st["respond"] == 0.0
+        assert sum(st.values()) == pytest.approx(clock() - t0)
+
+    def test_queue_wait_is_dispatch_minus_submit(self, program):
+        clock = ManualClock(10.0)
+        svc = CNNService(program, batch_size=2, clock=clock)
+        a = svc.submit(_images(1)[0])
+        clock.advance(0.25)
+        b = svc.submit(_images(1)[0])
+        clock.advance(0.5)
+        svc.step()
+        assert a.dispatch_t == b.dispatch_t == 10.75
+        for r in (a, b):
+            assert r.queue_wait_s == r.dispatch_t - r.submit_t
+        assert a.queue_wait_s == pytest.approx(0.75)
+        assert b.queue_wait_s == pytest.approx(0.5)
+
+    def test_batch_fill_on_a_partial_batch(self, program):
+        svc = CNNService(program, batch_size=4, clock=ManualClock())
+        for im in _images(3):
+            svc.submit(im)
+        svc.step()
+        st = svc.stats
+        assert (st["batch_slots"], st["images_batched"]) == (4, 3)
+        for im in _images(4):
+            svc.submit(im)
+        svc.step()
+        st = svc.stats
+        assert st["images_batched"] / st["batch_slots"] == 7 / 8
+
+    def test_step_ids_link_requests_to_steps(self, program):
+        clock = ManualClock()
+        svc = CNNService(program, batch_size=2, clock=clock)
+        assert svc.step() == []                  # an empty step is a step
+        reqs = [svc.submit(im) for im in _images(5)]
+        late = svc.submit(_images(1)[0], deadline_s=clock() + 0.5)
+        clock.advance(1.0)
+        svc.drain()
+        assert [r.step for r in reqs] == [1, 1, 2, 2, 3]
+        assert late.status == "shed" and late.step is None
+
+    def test_step_and_stage_spans_in_a_profiler_trace(self, program,
+                                                      tmp_path):
+        """Under ``jax.profiler`` each step is one ``binarray.serve.step``
+        span with its step id, rung and fill, holding one span per stage."""
+        import glob
+
+        svc = CNNService(program, batch_size=4, selftest_every=1)
+        for im in _images(6):
+            svc.submit(im)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        svc.drain()
+        jax.profiler.stop_trace()
+        path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+        events = [e for plane in
+                  jax.profiler.ProfileData.from_file(path).planes
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith("binarray.serve.")]
+        steps = [e for e in events if e.name == "binarray.serve.step"]
+        assert [dict(e.stats) for e in steps] == [
+            {"step": 0, "rung": 0, "filled": 4},
+            {"step": 1, "rung": 0, "filled": 2}]
+        for s in steps:
+            inside = [e.name.rsplit(".", 1)[1] for e in events
+                      if e is not s and s.start_ns <= e.start_ns
+                      and e.start_ns + e.duration_ns
+                      <= s.start_ns + s.duration_ns]
+            assert inside == list(STAGES)
 
 
 # ---------------------------------------------------------------------------

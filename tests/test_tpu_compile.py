@@ -12,6 +12,7 @@ module is imported: only one process at a time may load the TPU library,
 and it keeps it until it exits.
 """
 import os
+import re
 
 import jax
 import pytest
@@ -93,3 +94,43 @@ def test_instruction_compiles_for_v5e(programs, one_chip, key, name, m):
     compiled = jax.jit(
         lambda i, y: _apply(i, y, m, False)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+FAMILIES = ("binary_conv2d_pallas", "binary_dwconv2d_pallas",
+            "binary_matmul_pallas")
+
+
+def _placed(program, x_shape, one_chip):
+    def on_chip(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    return (jax.tree_util.tree_map(on_chip, program),
+            on_chip(jax.ShapeDtypeStruct(x_shape, "float32")))
+
+
+@pytest.mark.parametrize("key", sorted(PROGRAMS))
+def test_forward_kernels_carry_family_names(programs, one_chip, key):
+    """The whole forward compiled for v5e: every Mosaic kernel is named by
+    its family, whatever wraps it, and each instruction owns one."""
+    from repro.deploy import executor
+
+    program = programs[key]
+    args = _placed(program, program.input_shape, one_chip)
+    text = executor._execute_jit.lower(
+        *args, m_schedule=program.resolve_schedule(None),
+        interpret=False).compile().as_text()
+    kernels = re.findall(r"^\s*(?:ROOT )?%(\S+) = .* custom-call\(", text,
+                         re.M)
+    assert kernels and all(k.split(".")[0] in FAMILIES for k in kernels)
+    owners = executor._hlo_owners(text, [i.name for i in program.instrs])
+    assert [owners[k] for k in kernels] == [
+        f"i{i:02d}.{instr.name}" for i, instr in enumerate(program.instrs)]
+
+
+def test_every_cnn_a_op_has_an_owner(programs, one_chip):
+    from repro.deploy.executor import op_owners
+
+    program = programs["cnn_a"]
+    owners = op_owners(*_placed(program, program.input_shape, one_chip))
+    assert owners
+    assert "unattributed" not in owners.values(), owners

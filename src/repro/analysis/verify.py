@@ -185,11 +185,72 @@ def _verify_conv(instr: ConvInstr, idx: int, shape, budget: int):
     out_shape = (B, uo, V // instr.pool, D)
 
     plan = instr.plan
+    if plan.path not in ("tile", "row"):
+        fs.append(make_finding(
+            "plan-missing", name, idx,
+            f"conv plan needs a kernel path (tile | row), got {plan.path!r}"))
+        return out_shape, fs
+    with _no_pick_accounting():
+        want_path = bck.pick_conv_path(Hp, Wp, C, D, kh, kw,
+                                       stride=instr.stride, pool=instr.pool,
+                                       m=M)
+    if plan.path != want_path:
+        fs.append(make_finding(
+            "plan-noncanonical", name, idx,
+            f"path={plan.path!r} but pick_conv_path picks {want_path!r} "
+            f"for this layer (hand-built or stale plan)"))
+    if plan.path == "row":
+        got = _verify_row_plan(instr, idx, fs, B=B, Hp=Hp, Wp=Wp, C=C, D=D,
+                               U=U, uo=uo, budget=budget)
+    else:
+        got = _verify_tile_plan(instr, idx, fs, B=B, Hp=Hp, Wp=Wp, C=C,
+                                D=D, uo=uo, budget=budget)
+    if got is None:
+        return out_shape, fs
+    vmem_by_m, nb_e, rows = got
+
+    # stats drift + utilization warnings
+    st = instr.stats
+    if tuple(st.out_shape) and tuple(st.out_shape) != out_shape:
+        fs.append(make_finding(
+            "stats-drift", name, idx,
+            f"stats.out_shape {tuple(st.out_shape)} != derived {out_shape}"))
+    if tuple(st.padded_in) and tuple(st.padded_in) != (Hp, Wp):
+        fs.append(make_finding(
+            "stats-drift", name, idx,
+            f"stats.padded_in {tuple(st.padded_in)} != ({Hp}, {Wp})"))
+    macs = U * V * D * kh * kw * C
+    if st.macs and st.macs != macs:
+        fs.append(make_finding(
+            "stats-drift", name, idx,
+            f"stats.macs {st.macs} != derived {macs}"))
+    _check_stats_vmem(instr, idx, vmem_by_m, fs)
+    if B % nb_e:
+        fs.append(make_finding(
+            "ragged-batch", name, idx,
+            f"B={B} % nb={nb_e} != 0: last program carries "
+            f"{(-B) % nb_e} zero image(s)"))
+    occ = bck.mxu_row_occupancy(rows)
+    if occ < 0.5:
+        fs.append(make_finding(
+            "mxu-occupancy", name, idx,
+            f"MXU row occupancy {occ:.0%} under the plan (rows={rows})"))
+    return out_shape, fs
+
+
+def _verify_tile_plan(instr: ConvInstr, idx: int, fs, *, B, Hp, Wp, C, D,
+                      uo, budget):
+    """Tile-path plan checks; returns ``(vmem_by_m, nb_e, GEMM rows)`` or
+    None when the plan is incomplete."""
+    name, kh, kw = instr.name, instr.kh, instr.kw
+    M = instr.M
+    V = (Wp - kw) // instr.stride + 1
+    plan = instr.plan
     if plan.nb is None or plan.bu is None or plan.bd is None:
         fs.append(make_finding(
             "plan-missing", name, idx,
             f"conv plan needs (nb, bu, bd), got {plan}"))
-        return out_shape, fs
+        return None
     nb, bu, bd = plan.nb, plan.bu, plan.bd
     if not 1 <= nb <= B:
         fs.append(make_finding(
@@ -251,35 +312,56 @@ def _verify_conv(instr: ConvInstr, idx: int, shape, budget: int):
             "plan-noncanonical", name, idx,
             f"(nb={nb}, bu={bu}, bd={bd}) matches no pick_tile/pick_bu "
             f"choice for this layer (hand-built or stale plan)"))
+    return vmem_by_m, nb_e, bck.gemm_rows(nb_e, bu_e, V, pool=instr.pool)
 
-    # stats drift + utilization warnings
-    st = instr.stats
-    if tuple(st.out_shape) and tuple(st.out_shape) != out_shape:
+
+def _verify_row_plan(instr: ConvInstr, idx: int, fs, *, B, Hp, Wp, C, D, U,
+                     uo, budget):
+    """Row-path plan checks (whole images, ``bu`` must be ``Uo``); returns
+    ``(vmem_by_m, nb_e, GEMM rows)`` or None when the plan is
+    incomplete."""
+    name, kh, kw = instr.name, instr.kh, instr.kw
+    M = instr.M
+    plan = instr.plan
+    if plan.nb is None or plan.bu is None:
         fs.append(make_finding(
-            "stats-drift", name, idx,
-            f"stats.out_shape {tuple(st.out_shape)} != derived {out_shape}"))
-    if tuple(st.padded_in) and tuple(st.padded_in) != (Hp, Wp):
+            "plan-missing", name, idx,
+            f"row-path conv plan needs (nb, bu), got {plan}"))
+        return None
+    nb, bu = plan.nb, plan.bu
+    if instr.stride != 1:
         fs.append(make_finding(
-            "stats-drift", name, idx,
-            f"stats.padded_in {tuple(st.padded_in)} != ({Hp}, {Wp})"))
-    macs = U * V * D * kh * kw * C
-    if st.macs and st.macs != macs:
+            "plan-range", name, idx,
+            f"the row path runs stride 1 only, layer has {instr.stride}"))
+    if not 1 <= nb <= B:
         fs.append(make_finding(
-            "stats-drift", name, idx,
-            f"stats.macs {st.macs} != derived {macs}"))
-    _check_stats_vmem(instr, idx, vmem_by_m, fs)
-    if B % nb_e:
+            "plan-range", name, idx,
+            f"nb={nb} outside [1, B={B}] (kernel clamps silently)"))
+    if not 1 <= bu <= uo:
         fs.append(make_finding(
-            "ragged-batch", name, idx,
-            f"B={B} % nb={nb_e} != 0: last program carries "
-            f"{(-B) % nb_e} zero image(s)"))
-    occ = bck.mxu_row_occupancy(bck.gemm_rows(nb_e, bu_e, V, pool=instr.pool))
-    if occ < 0.5:
+            "plan-range", name, idx,
+            f"bu={bu} outside [1, Uo={uo}] (kernel clamps silently)"))
+    elif bu != uo:
         fs.append(make_finding(
-            "mxu-occupancy", name, idx,
-            f"MXU row occupancy {occ:.0%} under the plan "
-            f"(rows={bck.gemm_rows(nb_e, bu_e, V, pool=instr.pool)})"))
-    return out_shape, fs
+            "plan-noncanonical", name, idx,
+            f"bu={bu}: the row path always runs whole images (Uo={uo})"))
+    nb_e = max(1, min(nb, B))
+    geo = bck.conv_block_shapes(
+        Hp, Wp, C, D, kh, kw, bd=None, bu=uo, nb=nb_e, pool=instr.pool,
+        m=M, B=B, path="row")
+    for rule, msg in mosaic_rules.blocks_findings(name, geo["blocks"]):
+        fs.append(make_finding(rule, name, idx, msg))
+    vmem_by_m = {m: bck.row_vmem_bytes(Hp, Wp, C, D, kh, kw,
+                                       pool=instr.pool, m=m, nb=nb_e)
+                 for m in range(1, M + 1)}
+    worst = vmem_by_m[M]
+    if worst > budget:
+        fs.append(make_finding(
+            "vmem-budget", name, idx,
+            f"row-path working set {worst} B > budget {budget} B at m={M} "
+            f"(nb={nb_e})",
+            severity=mosaic_rules.WARN if nb_e == 1 else None))
+    return vmem_by_m, nb_e, nb_e * U
 
 
 def _verify_dwconv(instr: DWConvInstr, idx: int, shape, budget: int):

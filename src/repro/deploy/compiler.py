@@ -25,6 +25,7 @@ stats — with ShapeDtypeStruct weights, for free.
 from __future__ import annotations
 
 import dataclasses
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +43,8 @@ from repro.kernels import ops as kops
 from repro.models import cnn
 
 ARCHS = ("cnn_a", "mobilenet")
+
+log = logging.getLogger("repro.deploy")
 
 
 def _specs(arch):
@@ -88,43 +91,60 @@ def _compile_conv(spec, p, shape, quant):
     group_size = kh * kw * C // G
     m_plan = min(quant.m_active or M, M)
     budget = quant.conv_vmem_budget or bck.DEFAULT_VMEM_BUDGET
-
-    bd = kops._pick_block(D, 128)
-    if quant.conv_batch_tile is not None:
-        nb = max(1, min(quant.conv_batch_tile, B))
-        bu = bck.pick_bu(Hp, Wp, C, kh, kw, bd, spec.pool, budget,
-                         stride=spec.stride, m=m_plan, nb=nb)
-    else:
-        nb, bu = bck.pick_tile(B, Hp, Wp, C, kh, kw, bd, spec.pool, budget,
-                               stride=spec.stride, m=m_plan)
-
     uo = U // spec.pool
-    fused, im2col = bck.tile_hbm_bytes(
-        Wp, C, kh, kw, min(bd, D), bu=bu, pool=spec.pool, stride=spec.stride,
-        m=M, nb=nb, H=Hp)
-    rows_img = bck.gemm_rows(1, bu, V, pool=spec.pool)
+    path = bck.pick_conv_path(Hp, Wp, C, D, kh, kw, stride=spec.stride,
+                              pool=spec.pool, m=M)
+
+    if path == "row":
+        if quant.conv_batch_tile is not None:
+            nb = max(1, min(quant.conv_batch_tile, B))
+        else:
+            nb = bck.pick_row_nb(B, Hp, Wp, C, D, kh, kw, pool=spec.pool,
+                                 m=m_plan, budget_bytes=budget)
+        bu, bd, rows_img = uo, None, U
+        vmem = bck.row_vmem_bytes(Hp, Wp, C, D, kh, kw, pool=spec.pool,
+                                  m=m_plan, nb=nb)
+        fused = bck.row_hbm_bytes(Hp, Wp, C, D, kh, kw, pool=spec.pool, m=M,
+                                  nb=nb)
+        im2col = bck.tile_hbm_bytes(Wp, C, kh, kw, D, bu=uo, pool=spec.pool,
+                                    m=M, nb=nb, H=Hp)[1]
+        rows = nb * U
+    else:
+        bd = kops._pick_block(D, 128)
+        if quant.conv_batch_tile is not None:
+            nb = max(1, min(quant.conv_batch_tile, B))
+            bu = bck.pick_bu(Hp, Wp, C, kh, kw, bd, spec.pool, budget,
+                             stride=spec.stride, m=m_plan, nb=nb)
+        else:
+            nb, bu = bck.pick_tile(B, Hp, Wp, C, kh, kw, bd, spec.pool,
+                                   budget, stride=spec.stride, m=m_plan)
+        vmem = bck.tile_vmem_bytes(
+            Wp, C, kh, kw, bd, bu=bu, pool=spec.pool, stride=spec.stride,
+            m=m_plan, nb=nb)
+        fused, im2col = bck.tile_hbm_bytes(
+            Wp, C, kh, kw, min(bd, D), bu=bu, pool=spec.pool,
+            stride=spec.stride, m=M, nb=nb, H=Hp)
+        rows_img = bck.gemm_rows(1, bu, V, pool=spec.pool)
+        rows = bck.gemm_rows(nb, bu, V, pool=spec.pool)
+
     stats = LayerStats(
         in_shape=(B, H, W, C),
         out_shape=(B, uo, V // spec.pool, D),
         padded_in=(Hp, Wp),
         macs=U * V * D * kh * kw * C,
         weight_bytes=int(tap.size) + int(p["alpha"].size) * 4,
-        vmem_bytes=bck.tile_vmem_bytes(
-            Wp, C, kh, kw, bd, bu=bu, pool=spec.pool, stride=spec.stride,
-            m=m_plan, nb=nb),
+        vmem_bytes=vmem,
         hbm_fused_bytes=fused, hbm_im2col_bytes=im2col,
-        mxu_row_occupancy=bck.mxu_row_occupancy(
-            bck.gemm_rows(nb, bu, V, pool=spec.pool)),
+        mxu_row_occupancy=bck.mxu_row_occupancy(rows),
         batch_row_utilization=(bck.batch_row_utilization(B, nb, rows_img)
-                               if bu == uo else bck.mxu_row_occupancy(
-                                   bck.gemm_rows(nb, bu, V, pool=spec.pool))),
+                               if bu == uo else bck.mxu_row_occupancy(rows)),
     )
     instr = ConvInstr(
         B_tap_packed=tap, alpha=p["alpha"], bias=_bias(p, D),
         name=spec.name, kh=kh, kw=kw, stride=spec.stride,
         padding=spec.padding, pool=spec.pool, relu=spec.relu, pre=spec.pre,
         M=M, group_size=group_size,
-        plan=TilePlan(nb=nb, bu=bu, bd=bd), stats=stats)
+        plan=TilePlan(nb=nb, bu=bu, bd=bd, path=path), stats=stats)
     return instr, stats.out_shape
 
 
@@ -251,6 +271,8 @@ def compile(params: dict, arch: str, quant: QuantConfig,
         arch=arch if isinstance(arch, str) else "custom",
         input_shape=tuple(int(d) for d in input_shape),
         interpret=quant.interpret)
+    log.info("compiled %s %s: conv paths %s", program.arch,
+             program.input_shape, program.conv_paths())
     if golden is not False and not any(
             isinstance(leaf, jax.core.Tracer)
             for leaf in jax.tree_util.tree_leaves(program)):

@@ -78,7 +78,7 @@ def _apply(instr, y: jax.Array, m: int, interpret: bool) -> jax.Array:
             kh=instr.kh, kw=instr.kw, stride=instr.stride,
             padding=instr.padding, pool=instr.pool, m_active=m,
             relu=instr.relu, bd=instr.plan.bd, bu=instr.plan.bu,
-            nb=instr.plan.nb, interpret=interpret)
+            nb=instr.plan.nb, path=instr.plan.path, interpret=interpret)
     if isinstance(instr, DWConvInstr):
         return kops.binary_dwconv2d(
             y, instr.B_tap_packed, instr.alpha, instr.bias,

@@ -39,17 +39,19 @@ import jax
 
 @dataclasses.dataclass(frozen=True)
 class TilePlan:
-    """A frozen kernel schedule.  Convs use (nb, bu, bd); matmuls use
-    (bt, bn, bk); the depth-wise kernel uses (nb, bu).  Unused fields stay
-    None.  Every field is static — the plan lives in the pytree aux data, so
-    two programs with different plans compile to different executables."""
+    """A frozen kernel schedule.  Convs use (nb, bu, bd) on the tile path
+    and (nb, bu = Uo) on the row path (``path``); matmuls use (bt, bn, bk);
+    the depth-wise kernel uses (nb, bu).  Unused fields stay None.  Every
+    field is static — the plan lives in the pytree aux data, so two
+    programs with different plans compile to different executables."""
 
     nb: int | None = None   # conv/dw: images folded per program
     bu: int | None = None   # conv/dw: pooled output rows per program
-    bd: int | None = None   # conv: output-channel (MXU lane) tile
+    bd: int | None = None   # conv tile path: output-channel (MXU lane) tile
     bt: int | None = None   # matmul: row block
     bn: int | None = None   # matmul: output-column block
     bk: int | None = None   # matmul: reduction block
+    path: str | None = None  # conv: "tile" or "row" (pick_conv_path)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -292,7 +294,7 @@ class BinArrayProgram:
                          padded_in=list(i.stats.padded_in))
             if i.kind == "conv":
                 d.update(
-                    padding=i.padding, pool=i.pool,
+                    path=i.plan.path, padding=i.padding, pool=i.pool,
                     group_size=int(i.group_size),
                     hbm_fused_bytes=int(i.stats.hbm_fused_bytes),
                     hbm_im2col_bytes=int(i.stats.hbm_im2col_bytes),
@@ -303,6 +305,15 @@ class BinArrayProgram:
                 d.update(K=int(i.K), group_size=int(i.group_size))
             out.append(d)
         return out
+
+    def conv_paths(self) -> dict[str, int]:
+        """How many conv instructions run each fused-conv kernel path
+        (``"tile"`` / ``"row"``, see ``binary_conv.pick_conv_path``)."""
+        counts = {"tile": 0, "row": 0}
+        for i in self.instrs:
+            if i.kind == "conv":
+                counts[i.plan.path] = counts.get(i.plan.path, 0) + 1
+        return counts
 
     def totals(self) -> dict:
         """Whole-program roll-up of the per-layer stats."""

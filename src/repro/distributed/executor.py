@@ -112,7 +112,7 @@ def _layer_fn(mesh, axis_data: str, axis_model: str, shard: LayerShard,
                 kh=instr.kh, kw=instr.kw, stride=instr.stride,
                 padding=instr.padding, pool=instr.pool, m_active=m,
                 relu=instr.relu, bd=shard.plan.bd, bu=shard.plan.bu,
-                nb=shard.plan.nb, interpret=interpret)
+                nb=shard.plan.nb, path="tile", interpret=interpret)
             # disjoint channel slices -> tiled concat, no fp reduction:
             # bitwise equal to the unsharded conv output
             return jax.lax.all_gather(y_loc, axis_model,

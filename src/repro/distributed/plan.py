@@ -113,10 +113,12 @@ class MeshPlan:
 
 
 def _shardable(instr, n_model: int, *, pointwise_only: bool) -> bool:
-    """Structural preconditions for bd-sharding one instruction: ConvInstr,
-    point-wise (unless overridden), D divisible into >= 8-channel byte-even
-    slices (so the per-device lane dim stays Mosaic-padddable)."""
-    if n_model < 2 or not isinstance(instr, ConvInstr):
+    """Structural preconditions for bd-sharding one instruction: ConvInstr
+    on the tile path (a bd shard is a lane tile of it; the row path has
+    none), point-wise (unless overridden), D divisible into >= 8-channel
+    byte-even slices (so the per-device lane dim stays Mosaic-padddable)."""
+    if n_model < 2 or not isinstance(instr, ConvInstr) \
+            or instr.plan.path != "tile":
         return False
     if pointwise_only and not (instr.kh == 1 and instr.kw == 1):
         return False
@@ -180,7 +182,7 @@ def plan_mesh(program: BinArrayProgram, *, n_data: int, n_model: int = 1,
                 instr.pool, budget, stride=instr.stride, m=instr.M)
         shards.append(LayerShard(
             kind="bd", d_local=d_local,
-            plan=TilePlan(nb=nb_l, bu=bu_l, bd=bd_local),
+            plan=TilePlan(nb=nb_l, bu=bu_l, bd=bd_local, path="tile"),
             per_device_weight_bytes=wb // n_model))
     return MeshPlan(n_data=n_data, n_model=n_model, shards=tuple(shards),
                     global_batch=gb)

@@ -124,6 +124,36 @@ recovered as NB=1, BU=Uo and remains bit-exact with every other tiling.
 ``benchmarks/kernel_bench.py`` prints the analytic per-tile VMEM bytes, HBM
 bytes, and MXU row occupancy for the paper's layer shapes from these
 quantities.
+
+Row path: lane-dense row GEMM for lane-sparse inputs
+----------------------------------------------------
+With few input channels (CNN-A ``conv1``: C=3, D=5) the patch walk above
+is all overhead: every tap is a C-lane sliver of a 128-lane vreg (49 of
+them per conv row), and the MXU sees ``bd = 8`` output lanes.  For a
+stride-1 conv whose input has ``C < ROW_PATH_MAX_C`` channels,
+:func:`pick_conv_path` picks the row path instead when its working set
+fits ``DEFAULT_VMEM_BUDGET`` at the packed M:
+
+  * each input row is one lane-dense vector of ``L = Wp·C`` lanes, and the
+    patch row of conv row u is the ``kh`` row slabs ``x[u+i]`` side by
+    side, repeated per level: ``P [NB·U, m·kh·L]``;
+  * the weights become one banded matrix ``Band [m·kh·L, V·D]`` built in
+    the jitted forward from the packed bits (:func:`row_band`, data
+    movement only):
+    ``Band[(l, i, (v+j)·C + c), col(v, d)] = alpha_l·B_l[i, j, c, d]``, zero
+    elsewhere, so ``P @ Band`` is the conv at every column v at once;
+  * output lanes are ordered ``col(v, d) = ((v mod pool)·Vo + v div
+    pool)·D + d``, so column pooling is a max of ``pool`` contiguous lane
+    blocks; P's rows are ordered (image, ``u mod pool``, ``u div pool``) by
+    splitting the input rows by their residue mod pool in the wrapper, so
+    row pooling is a max of ``pool`` contiguous row blocks and every patch
+    slab is a contiguous load;
+  * the pooled ``[NB, Uo, Vo·D]`` tile is written with dense lanes.
+
+The contraction runs in the same fixed 128-row MXU passes as the tile
+path, so every NB is bit-identical.  The ``pallas_call`` keeps the name
+``binary_conv2d_pallas``: it is the same layer's kernel, and per-kernel
+device-time accounting finds the family by that name.
 """
 from __future__ import annotations
 
@@ -339,7 +369,8 @@ def tile_hbm_bytes(W: int, C: int, kh: int, kw: int, bd: int, *, bu: int,
 def conv_block_shapes(Hp: int, Wp: int, C: int, D: int, kh: int, kw: int, *,
                       bd: int, bu: int, nb: int, pool: int = 1,
                       stride: int = 1, m: int = 1, group_size: int | None
-                      = None, B: int | None = None) -> dict:
+                      = None, B: int | None = None,
+                      path: str = "tile") -> dict:
     """The exact BlockSpec geometry ``binary_conv2d_pallas`` builds for a
     (clamped) tile plan — exported so ``repro.analysis`` checks the real
     schedule instead of re-deriving its own.
@@ -350,7 +381,26 @@ def conv_block_shapes(Hp: int, Wp: int, C: int, D: int, kh: int, kw: int, *,
     SAME-resolved input dims; ``B`` defaults to one batch tile.  Callers must
     pass the clamped plan (``bd <= max(8, D)``, ``bu <= Uo``, ``nb <= B``) —
     the same values the kernel would execute.
+
+    ``path="row"`` gives the row path's geometry instead (``bd``/``bu``
+    unused): whole-image blocks over ``grid = (Bp // nb,)``, every lane dim
+    whole, no halo slabs (``nt = 1``, ``slab`` = the padded rows).
     """
+    b = B if B is not None else nb
+    Bp = b + (-b) % nb
+    if path == "row":
+        g = row_geometry(Hp, Wp, C, D, kh, kw, pool=pool, m=m)
+        x_rows = (pool, g["hq"], g["L"])
+        blocks = {
+            "x": ((nb, *x_rows), (Bp, *x_rows), "float32"),
+            "band": ((g["K"], g["N"]), (g["K"], g["N"]), "float32"),
+            "bias": ((1, g["no"]), (1, g["no"]), "float32"),
+            "out": ((nb, g["uo"], g["no"]), (Bp, g["uo"], g["no"]),
+                    "float32"),
+        }
+        return {"blocks": blocks, "grid": (Bp // nb,),
+                "padded_rows": pool * g["hq"], "slab": pool * g["hq"],
+                "adv": 0, "nt": 1}
     U = (Hp - kh) // stride + 1
     V = (Wp - kw) // stride + 1
     uo = max(U // pool, 1)
@@ -365,8 +415,6 @@ def conv_block_shapes(Hp: int, Wp: int, C: int, D: int, kh: int, kw: int, *,
     slab = slab_rows(bu, kh, stride=stride, pool=pool)
     rows_needed = (nt - 1) * adv + slab
     row_pad = max(rows_needed - Hp, 0)
-    b = B if B is not None else nb
-    Bp = b + (-b) % nb
     Wf, Cf = folded_width(Wp, C, stride)
     blocks = {
         "x": ((nb, slab, Wf, Cf), (Bp, Hp + row_pad, Wf, Cf), "float32"),
@@ -445,6 +493,144 @@ def pick_tile(B: int, H: int, W: int, C: int, kh: int, kw: int, bd: int,
         if best_key is None or key < best_key:
             best_nb, best_key = nb, key
     return best_nb, uo
+
+
+# ---------------------------------------------------------------------------
+# Row path sizing (see "Row path" in the module docstring)
+# ---------------------------------------------------------------------------
+
+# An input with fewer channels is lane-sparse: each tap of the patch walk
+# fills under 1/16 of a vreg's 128 lanes.
+ROW_PATH_MAX_C = 8
+# Images one row-path program folds at most (the kernel unrolls over them).
+ROW_PATH_MAX_NB = 32
+
+
+def row_geometry(Hp: int, Wp: int, C: int, D: int, kh: int, kw: int, *,
+                 pool: int = 1, m: int = 1) -> dict:
+    """Extents of the row path for a stride-1 conv over a padded
+    ``[Hp, Wp, C]`` image: conv rows/columns ``U``/``V``, pooled ``uo``/
+    ``vo``, input row width ``L = Wp·C``, rows per residue plane ``hq``
+    (the wrapper splits the input rows by their residue mod pool), the
+    band's ``[K, N] = [m·kh·L, V·D]`` and the pooled output lanes
+    ``no = vo·D``."""
+    U, V = Hp - kh + 1, Wp - kw + 1
+    L = Wp * C
+    return {"U": U, "V": V, "uo": U // pool, "vo": V // pool, "L": L,
+            "hq": -(-Hp // pool), "K": m * kh * L, "N": V * D,
+            "no": (V // pool) * D}
+
+
+def row_vmem_bytes(Hp: int, Wp: int, C: int, D: int, kh: int, kw: int, *,
+                   pool: int = 1, m: int = 1, nb: int = 1) -> int:
+    """VMEM footprint of one row-path program of ``nb`` whole images, as
+    Mosaic lays it out (every buffer padded to (8, 128) tiles, the
+    pipelined blocks counted twice):
+
+        x rows        nb·pool·hq·L·4       (the image, rows split mod pool)
+        band          m·kh·L · V·D · 4     (alpha-folded banded weights)
+        bias, out     vo·D·4, nb·uo·vo·D·4
+        P (scratch)   rows_pad · m·kh·L·4  (rows_pad = nb·U up to 128)
+        acc (scratch) rows_pad · V·D·4
+        values        two P row blocks being assembled, the pooled rows
+    """
+    tb = bmk.vmem_tile_bytes
+    g = row_geometry(Hp, Wp, C, D, kh, kw, pool=pool, m=m)
+    rows_pad = -(-(nb * g["U"]) // MXU_ROWS) * MXU_ROWS
+    blocks = (tb((nb * pool, g["hq"], g["L"])) + tb((g["K"], g["N"]))
+              + tb((1, g["no"])) + tb((nb, g["uo"], g["no"])))
+    scratch = tb((rows_pad, g["K"])) + tb((rows_pad, g["N"]))
+    values = 2 * tb((g["uo"], g["K"])) + (pool + 1) * tb((g["uo"], g["N"]))
+    return 2 * blocks + scratch + values
+
+
+def row_hbm_bytes(Hp: int, Wp: int, C: int, D: int, kh: int, kw: int, *,
+                  pool: int = 1, m: int = 1, nb: int = 1) -> int:
+    """HBM bytes one row-path program moves: its ``nb`` input images, the
+    f32 band (fetched once per call, counted per program as the tile path
+    counts its weight tile), the bias row and the pooled output tile."""
+    g = row_geometry(Hp, Wp, C, D, kh, kw, pool=pool, m=m)
+    return 4 * (nb * pool * g["hq"] * g["L"] + g["K"] * g["N"] + g["no"]
+                + nb * g["uo"] * g["no"])
+
+
+def pick_conv_path(Hp: int, Wp: int, C: int, D: int, kh: int, kw: int, *,
+                   stride: int = 1, pool: int = 1, m: int = 1) -> str:
+    """``"row"`` or ``"tile"``: which fused-conv kernel runs a layer.
+
+    Shapes alone decide: the row path takes stride-1 convs over
+    lane-sparse inputs (``C < ROW_PATH_MAX_C``) whose one-image working set
+    (:func:`row_vmem_bytes`, band included) fits ``DEFAULT_VMEM_BUDGET`` at
+    ``m`` levels — pass the packed M, so every §IV-D rung of a layer runs
+    the same path.  Every other layer keeps the tile path.  The deploy
+    compiler freezes the answer into the instruction's plan; the per-call
+    path (``ops.binary_conv2d``) asks the same question.
+    """
+    _note_plan_pick()
+    U, V = Hp - kh + 1, Wp - kw + 1
+    if stride != 1 or C >= ROW_PATH_MAX_C or U % pool or V % pool:
+        return "tile"
+    if row_vmem_bytes(Hp, Wp, C, D, kh, kw, pool=pool, m=m,
+                      nb=1) > DEFAULT_VMEM_BUDGET:
+        return "tile"
+    return "row"
+
+
+def pick_row_nb(B: int, Hp: int, Wp: int, C: int, D: int, kh: int, kw: int,
+                *, pool: int = 1, m: int = 1,
+                budget_bytes: int = DEFAULT_VMEM_BUDGET) -> int:
+    """Batch tile of the row path: the NB (at most ``ROW_PATH_MAX_NB``)
+    within the budget that minimizes the whole batch's padded MXU rows
+    (``batch_padded_rows`` at ``U`` rows per image), tie-broken toward
+    fewer programs — ``pick_tile``'s small-map rule."""
+    _note_plan_pick()
+    U = Hp - kh + 1
+    best_nb, best_key = 1, None
+    for nb in range(1, min(B, ROW_PATH_MAX_NB) + 1):
+        if nb > 1 and row_vmem_bytes(Hp, Wp, C, D, kh, kw, pool=pool, m=m,
+                                     nb=nb) > budget_bytes:
+            break
+        key = (batch_padded_rows(B, nb, U), -(-B // nb))
+        if best_key is None or key < best_key:
+            best_nb, best_key = nb, key
+    return best_nb
+
+
+def row_band(B_tap_packed: jax.Array, alpha: jax.Array, *, kh: int, kw: int,
+             C: int, Wp: int, pool: int, group_size: int) -> jax.Array:
+    """The row path's banded weights ``[m·kh·Wp·C, V·D]`` f32 from the
+    per-tap packed bits of the levels given (``[m, kh·kw, C8, D]``) and
+    their alpha ``[m, G, D]``.
+
+    Row ``(l, i, w·C + c)``, column ``col(v, d)`` holds ``alpha·B`` of level
+    l at tap ``(i, j = w − v)``, channel c, filter d when ``0 <= j < kw``,
+    else 0.  Pure data movement (tiles, broadcasts and a select on
+    ``j``), so every entry is exactly the kernel's alpha-folded ±1 weight
+    or 0; every step stays in a lane-dense ``[rows, V·D]`` layout.
+    """
+    m, T, C8, D = B_tap_packed.shape
+    K = kh * kw * C
+    w = bz.unpack_bits(B_tap_packed.reshape(m * T, C8, D), C8 * 8)
+    w = w[:, :C, :].astype(jnp.float32).reshape(m, K, D)
+    a = jnp.repeat(alpha.astype(jnp.float32), group_size, axis=1)
+    w_cat = (w * a).reshape(m, kh, kw, C, D)
+    V = Wp - kw + 1
+    vo = V // pool
+    R, N = m * kh * Wp * C, V * D
+    # w_cat[l, i, j, c, d] at every lane (v, d) of every row (l, i, w, c)
+    by_tap = jnp.tile(w_cat.transpose(2, 0, 1, 3, 4), (1, 1, 1, 1, V))
+    by_tap = jnp.broadcast_to(by_tap[:, :, :, None], (kw, m, kh, Wp, C, N))
+    by_tap = by_tap.reshape(kw, R, N)
+    # the tap column each (row, lane) reads: j = w − v, lane block q of
+    # pooled column t and phase p holding conv column v = t·pool + p
+    r = jax.lax.broadcasted_iota(jnp.int32, (R, N), 0)
+    q = jax.lax.broadcasted_iota(jnp.int32, (R, N), 1)
+    no = vo * D
+    j = (r % (Wp * C)) // C - ((q % no) // D * pool + q // no)
+    band = jnp.zeros((R, N), jnp.float32)
+    for jj in range(kw):
+        band = jnp.where(j == jj, by_tap[jj], band)
+    return band
 
 
 # ---------------------------------------------------------------------------
@@ -544,11 +730,115 @@ def _kernel(x_ref, bp_ref, alpha_ref, bias_ref, o_ref, p_ref, acc_ref, *,
     jax.lax.fori_loop(0, nb * bu, epilogue, 0)
 
 
+def _row_kernel(x_ref, band_ref, bias_ref, o_ref, p_ref, acc_ref, *,
+                kh: int, pool: int, nb: int, uo: int, m_active: int,
+                relu: bool):
+    """One program of the row path: NB whole images.
+
+    ``x_ref`` [NB, pool, hq, L] holds each image's rows split by residue
+    mod pool (plane r, row t = input row t·pool + r); ``p_ref``
+    [rows_pad, m·kh·L] and ``acc_ref`` [rows_pad, V·D] are the patch rows
+    and the GEMM result, one row per (image, u mod pool, u div pool).
+    """
+    U = uo * pool
+    no = o_ref.shape[-1]
+
+    # --- AGU: conv row u = t·pool + p reads input rows u + i, i < kh: for
+    # the uo rows of phase p that is one contiguous slab of plane
+    # (p + i) mod pool per tap row; side by side, once per level ---
+    for n in range(nb):
+        for p in range(pool):
+            slabs = [x_ref[n, (p + i) % pool, pl.ds((p + i) // pool, uo), :]
+                     for i in range(kh)]
+            r0 = n * U + p * uo
+            p_ref[r0:r0 + uo, :] = jnp.concatenate(
+                slabs * m_active, axis=1).astype(jnp.float32)
+    rows = nb * U
+    if p_ref.shape[0] > rows:
+        p_ref[rows:, :] = jnp.zeros((p_ref.shape[0] - rows, p_ref.shape[1]),
+                                    jnp.float32)
+
+    # --- PE/PA: the tile path's fixed MXU-row passes (tiling-invariant) ---
+    band = band_ref[...]
+
+    def one_pass(r, carry):
+        r0 = pl.multiple_of(r * MXU_ROWS, MXU_ROWS)
+        acc_ref[pl.ds(r0, MXU_ROWS), :] = dot_f32(
+            p_ref[pl.ds(r0, MXU_ROWS), :], band)
+        return carry
+
+    jax.lax.fori_loop(0, p_ref.shape[0] // MXU_ROWS, one_pass, 0)
+
+    # --- AMU: row pool = max of the pool phase blocks, column pool = max
+    # of the pool lane blocks; bias, ReLU, one dense-lane write per image ---
+    bias = bias_ref[...]                             # [1, vo·D]
+    for n in range(nb):
+        z = None
+        for p in range(pool):
+            r0 = n * U + p * uo
+            a = acc_ref[r0:r0 + uo, :]               # [uo, V·D]
+            z = a if z is None else jnp.maximum(z, a)
+        y = None
+        for p in range(pool):
+            s = z[:, p * no:(p + 1) * no]
+            y = s if y is None else jnp.maximum(y, s)
+        y = y + bias
+        if relu:
+            y = jnp.maximum(y, 0.0)
+        o_ref[n] = y
+
+
+def _binary_conv2d_rows(x, B_tap_packed, alpha, bias, *, kh, kw, pool,
+                        group_size, m_active, relu, nb, vmem_budget,
+                        interpret):
+    """The row path of :func:`binary_conv2d_pallas` (stride 1)."""
+    B, Hp, Wp, C = x.shape
+    D = B_tap_packed.shape[-1]
+    g = row_geometry(Hp, Wp, C, D, kh, kw, pool=pool, m=m_active)
+    if nb is None:
+        nb = pick_row_nb(B, Hp, Wp, C, D, kh, kw, pool=pool, m=m_active,
+                         budget_bytes=vmem_budget or DEFAULT_VMEM_BUDGET)
+    nb = max(1, min(nb, B))
+    b_rem = (-B) % nb                       # ragged batch: zero images
+    h_rem = pool * g["hq"] - Hp             # rows up to whole planes
+    if b_rem or h_rem:
+        x = jnp.pad(x, ((0, b_rem), (0, h_rem), (0, 0), (0, 0)))
+    Bp = B + b_rem
+    # lane-dense rows, split by residue mod pool: x[b, r, t] = row t·pool + r
+    x = x.reshape(Bp, g["hq"], pool, g["L"]).transpose(0, 2, 1, 3)
+    band = row_band(B_tap_packed[:m_active], alpha[:m_active], kh=kh, kw=kw,
+                    C=C, Wp=Wp, pool=pool, group_size=group_size)
+    bias_t = jnp.tile(bias.astype(jnp.float32), g["vo"]).reshape(1, g["no"])
+    rows_pad = -(-(nb * g["U"]) // MXU_ROWS) * MXU_ROWS
+    footprint = row_vmem_bytes(Hp, Wp, C, D, kh, kw, pool=pool, m=m_active,
+                               nb=nb)
+    x_rows = (pool, g["hq"], g["L"])
+    out = pl.pallas_call(
+        functools.partial(_row_kernel, kh=kh, pool=pool, nb=nb, uo=g["uo"],
+                          m_active=m_active, relu=relu),
+        grid=(Bp // nb,),
+        in_specs=[
+            pl.BlockSpec((nb, *x_rows), lambda b: (b, 0, 0, 0)),
+            pl.BlockSpec((g["K"], g["N"]), lambda b: (0, 0)),
+            pl.BlockSpec((1, g["no"]), lambda b: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((nb, g["uo"], g["no"]), lambda b: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bp, g["uo"], g["no"]), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((rows_pad, g["K"]), jnp.float32),
+                        pltpu.VMEM((rows_pad, g["N"]), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=bmk.vmem_limit_bytes(footprint)),
+        interpret=interpret,
+        name="binary_conv2d_pallas",
+    )(x, band, bias_t)
+    return out[:B].reshape(B, g["uo"], g["vo"], D)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("kh", "kw", "stride", "pool", "group_size",
                      "m_active", "relu", "bd", "bu", "nb", "vmem_budget",
-                     "interpret"),
+                     "interpret", "path"),
 )
 def binary_conv2d_pallas(
     x: jax.Array,
@@ -568,6 +858,7 @@ def binary_conv2d_pallas(
     nb: int | None = None,
     vmem_budget: int | None = None,
     interpret: bool = False,
+    path: str = "tile",
 ) -> jax.Array:
     """Fused binary conv + bias + 2D max-pool + ReLU.  fp32 output.
 
@@ -588,6 +879,11 @@ def binary_conv2d_pallas(
     Giving ``bu`` alone keeps per-image blocking (nb=1).  Every (nb, bu)
     tiling is bit-identical: each output element's concatenated m·K
     reduction is the same in every tiling.
+
+    ``path="row"`` runs the row path (module docstring; stride 1 only,
+    see :func:`pick_conv_path`): ``nb`` is its batch tile (None picks it
+    with :func:`pick_row_nb`), ``bd`` and ``bu`` are unused — it always
+    computes whole images over every output lane.
     """
     B, Hp, Wp, C = x.shape
     M, T, C8, D = B_tap_packed.shape
@@ -599,6 +895,13 @@ def binary_conv2d_pallas(
     assert U % pool == 0 and V % pool == 0, (U, V, pool)
     G = alpha.shape[1]
     assert G * group_size == kh * kw * C, (G, group_size, kh, kw, C)
+    if path == "row":
+        assert stride == 1, stride
+        return _binary_conv2d_rows(
+            x, B_tap_packed, alpha, bias, kh=kh, kw=kw, pool=pool,
+            group_size=group_size, m_active=m_active, relu=relu, nb=nb,
+            vmem_budget=vmem_budget, interpret=interpret)
+    assert path == "tile", path
 
     bd = min(bd, max(8, D))
     d_rem = (-D) % bd

@@ -110,16 +110,21 @@ def binary_conv2d(
     bu: int | None = None,
     nb: int | None = None,
     vmem_budget: int | None = None,
+    path: str | None = None,
 ) -> jax.Array:
     """Fused binary conv + bias + max-pool + ReLU via the Pallas kernel.
 
     x: [B, H, W, C] -> [B, U//pool, V//pool, D] in fp32.  The im2col tensor
     never touches HBM (patch extraction runs in VMEM inside the kernel).
-    ``nb``/``bu`` fix the batch tile (images folded into the GEMM row dim
-    per program) and the output row tile; leaving both None co-picks them
-    from the VMEM budget (kernels/binary_conv.py pick_tile) — NB grows on
-    small late-layer maps until the MXU row dim saturates, big maps keep
-    NB=1 and row-tile.
+    ``path`` selects the kernel: ``"tile"`` (implicit im2col) or ``"row"``
+    (lane-dense row GEMM for lane-sparse stride-1 inputs); None asks
+    :func:`~repro.kernels.binary_conv.pick_conv_path`, the same pick the
+    deploy compiler freezes.  On the tile path ``nb``/``bu`` fix the batch
+    tile (images folded into the GEMM row dim per program) and the output
+    row tile; leaving both None co-picks them from the VMEM budget
+    (kernels/binary_conv.py pick_tile) — NB grows on small late-layer maps
+    until the MXU row dim saturates, big maps keep NB=1 and row-tile.  The
+    row path takes ``nb`` only (None: ``pick_row_nb``).
     """
     from repro.core.binconv import same_pads
 
@@ -132,11 +137,17 @@ def binary_conv2d(
     K = kh * kw * C
     group_size = K // alpha.shape[1]
     D = alpha.shape[-1]
+    if path is None:
+        path = bck.pick_conv_path(
+            x.shape[1], x.shape[2], C, D, kh, kw, stride=stride, pool=pool,
+            m=B_tap_packed.shape[0])
+    if path == "tile" and bd is None:
+        bd = _pick_block(D, 128)
     return bck.binary_conv2d_pallas(
         x, B_tap_packed, alpha, bias,
         kh=kh, kw=kw, stride=stride, pool=pool, group_size=group_size,
-        m_active=m_active, relu=relu, bd=bd or _pick_block(D, 128),
-        bu=bu, nb=nb, vmem_budget=vmem_budget, interpret=interpret,
+        m_active=m_active, relu=relu, bd=bd, bu=bu, nb=nb,
+        vmem_budget=vmem_budget, interpret=interpret, path=path,
     )
 
 

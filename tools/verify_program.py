@@ -7,7 +7,8 @@ Runs, for each program in ``benchmarks.run.PROGRAMS`` (CNN-A,
 MobileNet-B1, MobileNet-B2):
 
   1. ``repro.analysis.verify_program`` on the abstract compile — Mosaic
-     block legality, packed widths, plan ranges, VMEM budget, stats drift;
+     block legality, packed widths, plan ranges, VMEM budget, stats drift
+     — and how many convs take each fused-conv path (tile / row);
   2. ``repro.analysis.trace_lint.lint_execute`` on the jitted execute jaxpr
      — zero fp conv primitives, zero trace-time plan picks, no f64
      (abstract tracing: nothing executes, so MobileNet-B2 @ 224² is cheap);
@@ -99,8 +100,10 @@ def main() -> int:
         summ = summarize(fs)
         n_errors += summ["errors"]
         doc[key] = {"summary": summ, "findings": [f.as_dict() for f in fs]}
+        doc[key]["conv_paths"] = prog.conv_paths()
         print(f"{key}: {summ['errors']} error(s), "
-              f"{summ['warnings']} warning(s)")
+              f"{summ['warnings']} warning(s), "
+              f"conv paths {prog.conv_paths()}")
         for f in fs:
             print(f"  {f}")
         if mesh is not None:

@@ -38,7 +38,8 @@ def key_of(seed: int, salt: int):
 
 
 def weight_shapes(config: dict) -> dict:
-    """name -> (weight shape, bias length), walked from the input shape."""
+    """name -> (weight shape, bias length), walked along the layer graph
+    from the input shape."""
     from harness.work import layers
 
     shapes = {}

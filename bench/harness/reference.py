@@ -201,11 +201,30 @@ def _conv(x, w, stride, padding, groups, precision):
     return _contract(fn, x, w, precision)
 
 
+def _padding(padding):
+    """"SAME", "VALID", or explicit [[top, bottom], [left, right]] pairs."""
+    return padding if isinstance(padding, str) else \
+        tuple(tuple(p) for p in padding)
+
+
+def _maxpool(y, k: int, stride: int, padding):
+    """Max over k x k windows at ``stride``, which may overlap; padding
+    reads -inf."""
+    pads = _padding(padding)
+    if not isinstance(pads, str):
+        pads = ((0, 0),) + pads + ((0, 0),)
+    return jax.lax.reduce_window(y, -jnp.inf, jax.lax.max, (1, k, k, 1),
+                                 (1, stride, stride, 1), pads)
+
+
 def forward(qparams: dict, x, config: dict, precision: str):
-    """Logits of images x [B, H, W, C] through the reconstructed weights."""
-    y = x
+    """Logits of images x [B, H, W, C] through the reconstructed weights,
+    along the layer graph of ``harness.work``'s vocabulary."""
+    y, outs = x, {}
     for spec in config["layers"]:
         p = qparams[spec["name"]]
+        if "src" in spec:
+            y = x if spec["src"] == "input" else outs[spec["src"]]
         pre = spec.get("pre", "none")
         if pre == "flatten":
             y = y.reshape(y.shape[0], -1)
@@ -217,15 +236,18 @@ def forward(qparams: dict, x, config: dict, precision: str):
         else:
             groups = y.shape[-1] if kind == "dwconv" else 1
             y = _conv(y, p["w"], spec.get("stride", 1),
-                      spec.get("padding", "VALID"), groups, precision)
+                      _padding(spec.get("padding", "VALID")), groups,
+                      precision)
             y = y + p["b"]
             pool = spec.get("pool", 1)
             if pool > 1:
-                n, h, w, c = y.shape
-                y = y.reshape(n, h // pool, pool, w // pool, pool, c)
-                y = y.max(axis=(2, 4))
+                y = _maxpool(y, pool, spec.get("pool_stride", pool),
+                             spec.get("pool_padding", "VALID"))
+        if "add" in spec:
+            y = y + outs[spec["add"]]
         if spec.get("relu", True):
             y = jnp.maximum(y, 0.0)
+        outs[spec["name"]] = y
     return y
 
 
